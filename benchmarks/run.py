@@ -210,6 +210,9 @@ def main() -> None:
                          "BENCH_kernel_perf.json)")
     args = ap.parse_args()
     full = args.full
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.check:
         from .common import ROOT_DIR

@@ -1,8 +1,9 @@
 """Decentralized training demo: 4 'pods' on this host, gradients synchronized
 by the paper's accelerated gossip instead of an all-reduce.
 
-Spawns a subprocess with 4 XLA host devices (the flag must be set before jax
-initializes), builds the (pod=4, data=1, model=1) mesh, and trains a small LM
+Spawns a subprocess with 4 XLA host devices on the CPU backend (the flags
+must be set before jax initializes; ``JAX_PLATFORMS=cpu`` keeps the demo off
+any attached accelerator, whose single chip could not hold a 4-pod mesh), builds the (pod=4, data=1, model=1) mesh, and trains a small LM
 with sync modes {allreduce, gossip, accel_gossip}, printing the loss curves
 and the consensus round counts (accel needs ~sqrt of the memoryless rounds).
 
@@ -20,11 +21,12 @@ from repro.models import build
 from repro.dist import make_train_step, SyncConfig
 from repro.data import SyntheticStream
 from repro import optim
+from repro.launch.mesh import make_mesh
 
 cfg = get_config("yi-9b", smoke=True)
 model = build(cfg)
 opt = optim.adamw(3e-3)
-mesh = jax.make_mesh((4, 1, 1), ("pod", "data", "model"))
+mesh = make_mesh((4, 1, 1), ("pod", "data", "model"))
 stream = SyntheticStream(cfg, global_batch=16, seq_len=64, seed=0)
 
 for mode in ("allreduce", "gossip", "accel_gossip"):
@@ -51,6 +53,7 @@ print("gradient mixing; rounds ratio gossip/accel shows the paper's speedup.")
 def main():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     r = subprocess.run([sys.executable, "-c", INNER], env=env)
     sys.exit(r.returncode)

@@ -10,11 +10,10 @@ Four jaxpr-level passes over the live registry and the sweep engine:
   grid traces to exactly one ``scan`` per backend, and no round body
   concretizes traced values (which would fragment the grid into per-cell
   retraces).
-- ``meshkernel`` — every ``pallas_call`` reachable under a mesh context is
-  behind the ``custom_partitioning`` rule from ``kernels/ops.py`` (an
-  unwrapped kernel is silently REPLICATED by GSPMD: every device runs the
-  full global grid), plus BlockSpec tile divisibility and the
-  ``segment_bn`` VMEM budget against declared shapes.
+- ``meshkernel`` — BlockSpec tile divisibility and the per-step VMEM
+  block budget of every ``pallas_call`` in the engine's program, against
+  declared shapes (under a mesh each device runs this same program on its
+  own block of cells).
 - ``precision`` — no weak-type float64 promotions or stray bfloat16
   accumulation inside the jitted scan bodies (the compression wire in
   ``repro.dist`` is the only sanctioned low-precision surface).

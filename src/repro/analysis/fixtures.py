@@ -10,7 +10,6 @@ verifying its own teeth.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,36 +50,6 @@ def _make_fixture_classes():
         def ref_coef(self, params):
             return (0.5, 0.5, 0.0)
 
-    class UnwrappedKernel(ConsensusAlgorithm):
-        """Supplies a raw pallas_call with no custom_partitioning wrapper."""
-
-        name = spec = "fx_unwrapped_kernel"
-        num_taps = 1
-
-        def round_body(self, prim, params, carry, t):
-            (x,) = carry
-            return (prim(x, x, _convex(x, 0.5, 0.5, 0.0)),)
-
-        def ref_coef(self, params):
-            return (0.5, 0.5, 0.0)
-
-        def pallas_round(self, ws, tiles=None):
-            from jax.experimental import pallas as pl
-            from repro.kernels.ops import use_interpret
-
-            def kernel(w_ref, x_ref, o_ref):
-                o_ref[...] = x_ref[...]
-
-            interp = use_interpret()
-
-            def prim(x, xp, coef, m=None):
-                return pl.pallas_call(
-                    kernel,
-                    out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-                    interpret=interp)(ws, x)
-
-            return prim
-
     class F64Promoter(ConsensusAlgorithm):
         """Multiplies state by a strong np.float64 scalar: x64 promotion."""
 
@@ -95,14 +64,13 @@ def _make_fixture_classes():
         def ref_coef(self, params):
             return (0.5, 0.5, 0.0)
 
-    return (MassLeaker, TickFragmenter, UnwrappedKernel, F64Promoter)
+    return (MassLeaker, TickFragmenter, F64Promoter)
 
 
 def fixture_specs():
     """(spec, pass name, expected rule, pass callable) per fixture."""
     from .coefficient import check_coefficient_mass
     from .compilation import check_compilation
-    from .meshkernel import check_mesh_kernels
     from .precision import check_precision
 
     return (
@@ -110,8 +78,6 @@ def fixture_specs():
          check_coefficient_mass),
         ("fx_fragmenting", "trace-compile", "retrace-fragmentation",
          check_compilation),
-        ("fx_unwrapped_kernel", "mesh-kernel", "mesh-unwrapped-kernel",
-         check_mesh_kernels),
         ("fx_f64_promoter", "precision", "weak-f64-promotion",
          check_precision),
     )

@@ -1,21 +1,17 @@
 """Pass 3: mesh/kernel contracts.
 
-Traces the engine's pallas program the way a MESH run lowers it
-(``force_mesh_dispatch`` routes the batched-round prim builders through
-their ``custom_partitioning`` wrappers even on a one-device analysis host)
-and checks, per ``pallas_call`` equation:
+Traces the engine's pallas program — the program every device runs on its
+own block of cells under a mesh (the engine wraps the whole scan in
+``shard_map`` over G, so no kernel is ever left to the partitioner) — and
+checks, per ``pallas_call`` equation:
 
-- ``mesh-unwrapped-kernel`` (error): the call is NOT nested under a
-  ``custom_partitioning`` eqn. GSPMD has no partitioning rule for an opaque
-  pallas call, so it silently REPLICATES it — every device runs the full
-  global grid and the mesh buys nothing (or worse, produces wrong shards).
 - ``kernel-tile-divisibility`` (error): a BlockSpec tile does not divide
   its operand extent — the kernel would read OOB-masked garbage or the
   lowering would fail at compile time, long after the sweep was scheduled.
 - ``kernel-vmem-budget`` (error): the per-grid-step block working set
-  (every input/output block, double-buffered) exceeds the segment VMEM
-  policy budget (``kernels.ops._SEGMENT_VMEM_BUDGET``, the bound
-  ``segment_bn`` enforces when it picks the source-block size).
+  (every input/output block, double-buffered) exceeds ``VMEM_BLOCK_BUDGET``
+  — half the TPU v5e's 16 MiB default scoped VMEM, leaving the rest to
+  Mosaic's own temporaries.
 
 Both the dense batched-round program and the sparse ELL segment program
 are traced; ``dist/gossip.py``'s coverage of registry algorithms gets an
@@ -28,6 +24,8 @@ from .findings import AnalysisFinding, source_of
 from . import trace_utils as tu
 
 PASS = "mesh-kernel"
+
+VMEM_BLOCK_BUDGET = 8 * 1024 * 1024  # bytes, double-buffered block set
 
 
 def _kernel_name(eqn) -> str:
@@ -66,18 +64,9 @@ def _block_shapes(eqn):
     return out
 
 
-def check_pallas_eqn(eqn, inside_cp: bool) -> list[AnalysisFinding]:
-    from repro.kernels import ops
-
+def check_pallas_eqn(eqn) -> list[AnalysisFinding]:
     name = _kernel_name(eqn)
     findings = []
-    if not inside_cp:
-        findings.append(_kernel_finding(
-            "mesh-unwrapped-kernel", "error",
-            "pallas_call reachable under a mesh context is not wrapped by "
-            "the custom_partitioning rule from kernels/ops.py — GSPMD "
-            "silently replicates it (every device runs the full global "
-            "grid)", name))
     vmem = 0
     for bs, shape, dtype in _block_shapes(eqn):
         if len(bs) == len(shape):
@@ -92,12 +81,11 @@ def check_pallas_eqn(eqn, inside_cp: bool) -> list[AnalysisFinding]:
         for bd in (bs if bs else shape):
             n_elem *= max(int(bd), 1)
         vmem += n_elem * dtype.itemsize
-    budget = ops._SEGMENT_VMEM_BUDGET
-    if 2 * vmem > budget:  # double-buffered pipeline working set
+    if 2 * vmem > VMEM_BLOCK_BUDGET:  # double-buffered pipeline working set
         findings.append(_kernel_finding(
             "kernel-vmem-budget", "error",
-            f"per-step block working set 2*{vmem}B exceeds the segment "
-            f"VMEM policy budget {budget}B (segment_bn's bound)", name))
+            f"per-step block working set 2*{vmem}B exceeds the kernel "
+            f"VMEM block budget {VMEM_BLOCK_BUDGET}B", name))
     return findings
 
 
@@ -126,23 +114,23 @@ def check_mesh_kernels(algorithms=None) -> list[AnalysisFinding]:
     findings: list[AnalysisFinding] = []
     traces = []
     try:
-        traces.append(tu.trace_engine(specs, "pallas", force_mesh=True))
+        traces.append(tu.trace_engine(specs, "pallas"))
     except Exception as exc:
         findings.append(_kernel_finding(
             "engine-trace-failed", "error",
-            f"dense pallas grid failed to trace under forced mesh "
-            f"dispatch: {exc}", "sweep.engine[pallas]"))
+            f"dense pallas grid failed to trace: {exc}",
+            "sweep.engine[pallas]"))
     try:
-        traces.append(tu.trace_engine_sparse(specs, force_mesh=True))
+        traces.append(tu.trace_engine_sparse(specs))
     except Exception as exc:
         findings.append(_kernel_finding(
             "engine-trace-failed", "error",
-            f"sparse pallas grid failed to trace under forced mesh "
-            f"dispatch: {exc}", "sweep.engine[pallas-sparse]"))
+            f"sparse pallas grid failed to trace: {exc}",
+            "sweep.engine[pallas-sparse]"))
     for closed in traces:
-        for eqn, inside_cp in tu.iter_eqns(closed.jaxpr):
+        for eqn in tu.iter_eqns(closed.jaxpr):
             if eqn.primitive.name == "pallas_call":
-                findings.extend(check_pallas_eqn(eqn, inside_cp))
+                findings.extend(check_pallas_eqn(eqn))
     if algorithms is None:  # registry-wide advisory, not per-spec
         findings.extend(_check_dist_coverage())
     # the same kernel appears once per partition/branch: dedup exact repeats

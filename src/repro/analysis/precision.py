@@ -6,10 +6,10 @@ in ``repro.dist`` (stochastic-rounding bfloat16 on the gossip exchange).
 Two statically-detectable ways to break that:
 
 - ``weak-f64-promotion`` (error): a Python float closing over a round body
-  is weakly typed; under ``jax.experimental.enable_x64`` it promotes the
+  is weakly typed; under ``jax.enable_x64`` it promotes the
   whole chain to float64 — 2x memory, several-x slower, and silently
   different roundoff between x64-enabled hosts and default ones. We trace
-  each round body (and the full engine scan) INSIDE ``enable_x64()`` with
+  each round body (and the full engine scan) INSIDE ``jax.enable_x64(True)`` with
   f32 operands: any f64 eqn output that is not an explicit cast is a
   promotion leak.
 - ``bf16-accumulation`` (error): a bfloat16 (or fp16) array inside the
@@ -41,7 +41,7 @@ def _f64_eqns(closed):
     deliberately exempt: index arithmetic legitimately widens under x64.
     """
     hits = []
-    for eqn, _ in tu.iter_eqns(closed.jaxpr):
+    for eqn in tu.iter_eqns(closed.jaxpr):
         if eqn.primitive.name in ("pjit", "scan", "custom_partitioning",
                                   "pallas_call", "while", "cond"):
             continue  # containers: their inner eqns are walked anyway
@@ -53,7 +53,7 @@ def _f64_eqns(closed):
 
 def _low_prec_vars(closed):
     hits = []
-    for eqn, _ in tu.iter_eqns(closed.jaxpr):
+    for eqn in tu.iter_eqns(closed.jaxpr):
         for v in eqn.outvars:
             dt = str(getattr(v.aval, "dtype", ""))
             if dt in ("bfloat16", "float16"):
@@ -68,7 +68,7 @@ def check_precision(algorithms=None):
     findings: list[AnalysisFinding] = []
 
     # per-registration: round body traced under x64 semantics on f32 operands
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for spec in specs:
             algo = get_algorithm(spec)
             ens = tu.probe_ensemble(algo.spec)

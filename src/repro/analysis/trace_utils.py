@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.4.x moved Primitive to jax.extend
-    from jax.extend.core import Primitive
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Primitive
+from jax.extend.core import Primitive
 
 from jax import core as jax_core
 
@@ -129,17 +126,16 @@ def subjaxprs_of(eqn):
                 yield x
 
 
-def iter_eqns(jaxpr, inside_cp: bool = False):
-    """Yield (eqn, inside_custom_partitioning) over a jaxpr, recursively."""
+def iter_eqns(jaxpr):
+    """Yield every eqn of a jaxpr, recursively into sub-jaxprs."""
     for eqn in jaxpr.eqns:
-        yield eqn, inside_cp
-        sub_cp = inside_cp or eqn.primitive.name == "custom_partitioning"
+        yield eqn
         for sub in subjaxprs_of(eqn):
-            yield from iter_eqns(sub, sub_cp)
+            yield from iter_eqns(sub)
 
 
 def count_primitive(jaxpr, name: str) -> int:
-    return sum(1 for eqn, _ in iter_eqns(jaxpr) if eqn.primitive.name == name)
+    return sum(1 for eqn in iter_eqns(jaxpr) if eqn.primitive.name == name)
 
 
 # ---------------------------------------------------------------------------
@@ -162,16 +158,9 @@ def build_probe_grid(specs, *, num_iters: int = 4, seed: int = 0):
     return ens, masks
 
 
-def trace_engine(specs, backend: str, *, num_iters: int = 4,
-                 force_mesh: bool = False):
-    """ClosedJaxpr of the whole sweep scan over ``specs`` on ``backend``.
-
-    ``force_mesh=True`` traces the program a MESH run would lower (the
-    batched kernels behind their custom_partitioning wrappers) even on a
-    one-device analysis host — the mesh/kernel pass's view.
-    """
+def trace_engine(specs, backend: str, *, num_iters: int = 4):
+    """ClosedJaxpr of the whole sweep scan over ``specs`` on ``backend``."""
     from repro.core.algorithms import registry_generation
-    from repro.kernels import ops as kops
     from repro.sweep import engine
 
     ens, masks = build_probe_grid(specs, num_iters=num_iters)
@@ -207,14 +196,10 @@ def trace_engine(specs, backend: str, *, num_iters: int = 4,
         None if bits is None else jax.ShapeDtypeStruct(bits.shape, jnp.uint8),
         None if eidx is None else jax.ShapeDtypeStruct(eidx.shape, jnp.int32),
     )
-    if force_mesh:
-        with kops.force_mesh_dispatch():
-            return jax.make_jaxpr(fn)(*avals)
     return jax.make_jaxpr(fn)(*avals)
 
 
-def trace_engine_sparse(specs, *, num_iters: int = 4,
-                        force_mesh: bool = False):
+def trace_engine_sparse(specs, *, num_iters: int = 4):
     """ClosedJaxpr of the sparse-pallas (ELL segment-kernel) sweep scan.
 
     Replays ``engine._prep_pallas_sparse`` host-side (numpy-only ELL build —
@@ -222,7 +207,6 @@ def trace_engine_sparse(specs, *, num_iters: int = 4,
     footprint appear in the trace the mesh/kernel pass inspects.
     """
     from repro.core.algorithms import registry_generation
-    from repro.kernels import ops as kops
     from repro.sweep import engine
     from repro.sweep.grid import SweepSpec, build_ensemble, build_round_masks
 
@@ -236,12 +220,12 @@ def trace_engine_sparse(specs, *, num_iters: int = 4,
     if masks is not None:
         bits = np.asarray(masks.bits, np.uint8)
         eidx = np.asarray(masks.idx, np.int32)
-    x0, wpack, tiles, bits, n, f = engine._prep_pallas_sparse(
+    x0, wpack, tiles, n, f = engine._prep_pallas_sparse(
         np.asarray(ens.x0, np.float32),
         np.asarray(ens.edges, np.int32), np.asarray(ens.edge_w, np.float32),
         np.asarray(ens.diag_w, np.float32), ens.edge_counts,
         None if ens.edge_w_rev is None
-        else np.asarray(ens.edge_w_rev, np.float32), bits)
+        else np.asarray(ens.edge_w_rev, np.float32))
 
     raw = engine._sweep_scan.__wrapped__
     statics = dict(
@@ -261,7 +245,4 @@ def trace_engine_sparse(specs, *, num_iters: int = 4,
         None if bits is None else jax.ShapeDtypeStruct(bits.shape, jnp.uint8),
         None if eidx is None else jax.ShapeDtypeStruct(eidx.shape, jnp.int32),
     )
-    if force_mesh:
-        with kops.force_mesh_dispatch():
-            return jax.make_jaxpr(fn)(*avals)
     return jax.make_jaxpr(fn)(*avals)

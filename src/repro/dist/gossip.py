@@ -190,51 +190,59 @@ def _fma(a: float, b: float, c: float) -> float:
     return float(Fraction(a) * Fraction(b) + Fraction(c))
 
 
+def _sender_weights(perm, w: np.ndarray) -> np.ndarray:
+    """wsend[s] = W[d, s] for each (src s, dst d) of one matching, else 0."""
+    wsend = np.zeros(w.shape[0], dtype=w.dtype)
+    for s, d in perm:
+        wsend[s] = w[d, s]
+    return wsend
+
+
 def fabric_matvec(w: np.ndarray, contraction: str = "fma"):
-    """Host mirror of the in-mesh neighbour sum — the bit-for-bit reference
-    for Algorithm 1 agreement tests
+    """Host mirror of ``distributed_lambda2``'s in-mesh matvec — the
+    bit-for-bit reference for Algorithm 1 agreement tests
     (``doi.estimate_lambda2(..., matvec=fabric_matvec(w))``).
 
-    ``contraction`` selects the floating-point recipe:
+    Each matching delivers the rounded product W[d, s] * v[s] its sender
+    computed; the receiver adds them to its own diagonal term in matching
+    order. ``contraction`` selects how that diagonal term is combined:
 
-    * ``"fma"`` — mirror LLVM's mul+add contraction as XLA:CPU emits it for
-      ``_neighbor_sum``: the first accumulation fuses the diagonal product
-      (``fma(W_ii, v_i, p_0)``), every later matching fuses its own product
-      (``fma(wvec_k, recv_k, acc)``). Emulated with exact rational arithmetic
-      and a single rounding per fma, so the host trajectory reproduces the
-      jitted SPMD trajectory bit for bit.
+    * ``"fma"`` — as XLA:CPU emits it: the diagonal product fuses into the
+      first addition (``fma(W_ii, v_i, p_0)``, one rounding), later terms
+      are plain adds. Emulated with exact rational arithmetic, so the host
+      trajectory reproduces the jitted SPMD trajectory bit for bit.
     * ``"none"`` — plain mul-then-add (the reference on backends that do not
       contract).
     """
     if contraction not in ("fma", "none"):
         raise ValueError(f"unknown contraction {contraction!r}")
     diag = np.diag(w).copy()
-    perms = edge_permutations(w)
+    sends = [(perm, _sender_weights(perm, w)) for perm, _ in edge_permutations(w)]
 
-    def recv_of(v, perm):
-        recv = np.zeros_like(v)
-        for s, d in perm:
-            recv[d] = v[s]
-        return recv
-
-    def mv_plain(v: np.ndarray) -> np.ndarray:
-        out = diag * v
-        for perm, wvec in perms:
-            out = out + wvec * recv_of(v, perm)
+    def received(v):
+        out = []
+        for perm, wsend in sends:
+            recv = np.zeros_like(v)
+            prod = wsend * v
+            for s, d in perm:
+                recv[d] = prod[s]
+            out.append(recv)
         return out
 
-    def mv_fma(v: np.ndarray) -> np.ndarray:
-        if not perms:
+    def mv(v: np.ndarray) -> np.ndarray:
+        terms = received(v)
+        if not terms:
             return diag * v
-        (perm0, wvec0), rest = perms[0], perms[1:]
-        p0 = wvec0 * recv_of(v, perm0)
-        out = np.array([_fma(diag[i], v[i], p0[i]) for i in range(len(v))])
-        for perm, wvec in rest:
-            recv = recv_of(v, perm)
-            out = np.array([_fma(wvec[i], recv[i], out[i]) for i in range(len(v))])
+        if contraction == "fma":
+            out = np.array([_fma(diag[i], v[i], terms[0][i])
+                            for i in range(len(v))])
+        else:
+            out = diag * v + terms[0]
+        for recv in terms[1:]:
+            out = out + recv
         return out
 
-    return mv_fma if contraction == "fma" else mv_plain
+    return mv
 
 
 def _neighbor_sum(x_self, payload, axis_name, idx, diag, perms, live=None):
@@ -243,8 +251,7 @@ def _neighbor_sum(x_self, payload, axis_name, idx, diag, perms, live=None):
     ``x_self`` is the node's true state (never quantized); ``payload`` is what
     goes on the wire. One ppermute per matching; nodes outside a matching
     receive zeros and carry a zero weight, so the same program serves every
-    fabric topology. The accumulation is written mul-then-add; XLA:CPU
-    contracts it to the fma chain ``fabric_matvec(w, "fma")`` mirrors.
+    fabric topology.
 
     ``live`` (optional, one 0/1 scalar per matching) marks which matchings
     delivered this round. A dead matching's weight returns to the node's own
@@ -608,14 +615,24 @@ def distributed_lambda2(
         num_iters = default_doi_iters(fab, dtype)
     idx = jax.lax.axis_index(axis_name)
     diag = jnp.asarray(np.diag(fab.w), dtype)
-    perms = [(perm, jnp.asarray(wvec, dtype))
-             for perm, wvec in edge_permutations(fab.w)]
+    perms = edge_permutations(fab.w)
     adj = (np.abs(fab.w) > 0).astype(np.float64)
     np.fill_diagonal(adj, 0.0)
     diam = topology.diameter(adj)
 
+    # Senders scale their own value before the exchange (wsend[s] = W[d, s]
+    # for each (s, d) of a matching), so every received term is a rounded
+    # product and the only mul+add left for a code generator to contract is
+    # the diagonal one: the iterate's arithmetic does not hinge on how a
+    # backend fuses the accumulation, and ``fabric_matvec`` mirrors it.
+    sends = [(perm, jnp.asarray(_sender_weights(perm, fab.w), dtype))
+             for perm, _ in perms]
+
     def matvec(v):
-        return _neighbor_sum(v, v, axis_name, idx, diag, perms)
+        out = diag[idx] * v
+        for perm, wsend in sends:
+            out = out + jax.lax.ppermute(wsend[idx] * v, axis_name, perm)
+        return out
 
     def max_consensus(m):
         # |v| >= 0, so the zero fill of off-matching ppermute slots is the

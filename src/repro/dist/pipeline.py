@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_forward", "reference_forward"]
@@ -72,5 +72,5 @@ def pipeline_forward(w1, w2, x, mesh, axis_name: str = "stage"):
         body, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(w1, w2, x)

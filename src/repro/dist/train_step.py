@@ -28,7 +28,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import optim
@@ -187,7 +187,7 @@ def make_train_step(
             )
 
         synced = shard_map(
-            body, mesh=mesh, in_specs=specs, out_specs=specs, check_rep=False
+            body, mesh=mesh, in_specs=specs, out_specs=specs, check_vma=False
         )(*flat)
         return jax.tree.unflatten(treedef, synced)
 

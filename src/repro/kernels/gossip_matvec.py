@@ -33,7 +33,8 @@ def gossip_matvec_kernel(w_ref, x_ref, y_ref):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     y_ref[...] += jnp.dot(
-        w_ref[...], x_ref[...], preferred_element_type=jnp.float32
+        w_ref[...], x_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

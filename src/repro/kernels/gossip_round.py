@@ -13,17 +13,21 @@ through HBM: per round this saves one full write + one full read of the
 (N, F) state block, on top of the second kernel's launch and its extra
 X read — the simulator's inner loop runs thousands of such rounds.
 
-Grid layout (single graph): (N/bm, F/bf, N/bk) with K innermost, exactly as
-in ``gossip_matvec`` — the output index map ignores k, so Pallas keeps the
-(bm, bf) block resident across the contraction. X is passed twice with two
+Grid layout: (G, N/bm, F/bf, N/bk) with K innermost, exactly as in
+``gossip_matvec`` — the output index map ignores k, so Pallas keeps the
+(bm, bf) block resident across the contraction. A leading G grid axis
+indexes a (G, N, N) stacked topology ensemble with per-graph coefficients
+(G, 3) — one kernel launch evaluates a full topology x theta x alpha sweep
+grid (a single graph is the G = 1 case). Blocks carry a leading length-1
+graph dim which is squeezed inside the kernel. X is passed twice with two
 different index maps: (kk, j) tiles feed the MXU contraction; the (i, j)
-tile (k-independent, fetched once) provides the ``b * X`` tap.
+tile (k-independent, fetched once) provides the ``b * X`` tap. The per-cell
+(a, b, c) row is a (1, 1, 3) SMEM block of the (G, 1, 3) coefficient stack:
+a (1, 3) VMEM block of a (G, 3) array breaks the TPU's (8, 128) tiling rule.
 
-Batched variant: a leading G grid axis indexes a (G, N, N) stacked topology
-ensemble with per-graph coefficients (G, 3) — one kernel launch evaluates a
-full topology x theta x alpha sweep grid. The sweep engine
-(``repro.sweep.engine``) drives this directly; blocks carry a leading
-length-1 graph dim which is squeezed inside the kernel.
+Every contraction runs at ``precision=HIGHEST`` (f32 on the MXU): the
+rounds rely on W being exactly doubly (or column) stochastic, and a single
+bf16 pass would round W's entries, drifting the mean round after round.
 
 VMEM budget per step at the default 128/128/512 tiles, fp32: out 256 KB +
 W 64 KB + three X-shaped tiles 768 KB — comfortably inside ~16 MB.
@@ -35,81 +39,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "gossip_round_kernel",
-    "gossip_round_pallas",
+    "COEF_SPEC",
     "gossip_round_batched_kernel",
     "gossip_round_batched_pallas",
-    "gossip_round_masked_kernel",
-    "gossip_round_masked_pallas",
     "gossip_round_masked_batched_kernel",
     "gossip_round_masked_batched_pallas",
     "gossip_round_sender_masked_batched_kernel",
     "gossip_round_sender_masked_batched_pallas",
 ]
 
-
-def gossip_round_kernel(nk: int, coef_ref, w_ref, xk_ref, xi_ref, xp_ref, y_ref):
-    """Accumulate one (bm,bk)@(bk,bf) partial product; FMA on the last K step."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    y_ref[...] += jnp.dot(
-        w_ref[...], xk_ref[...], preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        y_ref[...] = a * y_ref[...] + b * xi_ref[...] + c * xp_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "bf", "interpret"))
-def gossip_round_pallas(
-    w: jax.Array,
-    x: jax.Array,
-    xp: jax.Array,
-    coef: jax.Array,
-    *,
-    bm: int = 128,
-    bk: int = 128,
-    bf: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused Y = coef[0]*(W@X) + coef[1]*X + coef[2]*Xp, operands pre-padded.
-
-    ``coef`` is a (1, 3) traced array [a, b, c] (alpha* may be computed
-    in-program from a DOI lambda_2 estimate). Shape management lives in
-    ``repro.kernels.ops.gossip_round``.
-    """
-    n, k = w.shape
-    k2, f = x.shape
-    if k != k2 or x.shape != xp.shape:
-        raise ValueError(f"shape mismatch: W {w.shape}, X {x.shape}, Xp {xp.shape}")
-    if n % bm or k % bk or f % bf:
-        raise ValueError(f"shapes ({n},{k},{f}) not multiples of tiles ({bm},{bk},{bf})")
-    nk = k // bk
-    grid = (n // bm, f // bf, nk)
-    return pl.pallas_call(
-        functools.partial(gossip_round_kernel, nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bf), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
-        interpret=interpret,
-    )(coef, w, x, x, xp)
+_HIGHEST = jax.lax.Precision.HIGHEST
+# per-cell (a, b, c): one (1, 1, 3) SMEM block of the (G, 1, 3) stack, for
+# any grid whose leading axis is G
+COEF_SPEC = pl.BlockSpec((1, 1, 3), lambda gg, *_: (gg, 0, 0),
+                          memory_space=pltpu.SMEM)
 
 
 def gossip_round_batched_kernel(nk: int, coef_ref, w_ref, xk_ref, xi_ref, xp_ref, y_ref):
@@ -121,14 +67,15 @@ def gossip_round_batched_kernel(nk: int, coef_ref, w_ref, xk_ref, xi_ref, xp_ref
         y_ref[...] = jnp.zeros_like(y_ref)
 
     y_ref[0] += jnp.dot(
-        w_ref[0], xk_ref[0], preferred_element_type=jnp.float32
+        w_ref[0], xk_ref[0], preferred_element_type=jnp.float32,
+        precision=_HIGHEST,
     )
 
     @pl.when(k == nk - 1)
     def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
+        a = coef_ref[0, 0, 0]
+        b = coef_ref[0, 0, 1]
+        c = coef_ref[0, 0, 2]
         y_ref[...] = a * y_ref[...] + b * xi_ref[...] + c * xp_ref[...]
 
 
@@ -164,7 +111,7 @@ def gossip_round_batched_pallas(
         functools.partial(gossip_round_batched_kernel, nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, kk: (gg, 0)),
+            COEF_SPEC,
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
             pl.BlockSpec((1, bk, bf), lambda gg, i, j, kk: (gg, kk, j)),
             pl.BlockSpec((1, bm, bf), lambda gg, i, j, kk: (gg, i, j)),
@@ -173,7 +120,7 @@ def gossip_round_batched_pallas(
         out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, ws, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), ws, xs, xs, xps)
 
 
 # ---------------------------------------------------------------------------
@@ -192,80 +139,6 @@ def gossip_round_batched_pallas(
 # ---------------------------------------------------------------------------
 
 
-def gossip_round_masked_kernel(nk: int, coef_ref, w_ref, m_ref, xk_ref, xi_ref,
-                               xp_ref, y_ref):
-    """Masked matvec + dropped-mass return per K tile; FMA on the last step."""
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    w = w_ref[...]
-    wm = w * m_ref[...]
-    # dropped mass of this K tile's columns returns to the diagonal: the
-    # (bm, 1) row sum of W .* (1 - M) scales the node's own (i, j) X tile,
-    # accumulating the diag((W .* (1-M)) @ 1) @ X term across the contraction.
-    drop = jnp.sum(w - wm, axis=1, keepdims=True)
-    y_ref[...] += (
-        jnp.dot(wm, xk_ref[...], preferred_element_type=jnp.float32)
-        + drop * xi_ref[...]
-    )
-
-    @pl.when(k == nk - 1)
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        y_ref[...] = a * y_ref[...] + b * xi_ref[...] + c * xp_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "bf", "interpret"))
-def gossip_round_masked_pallas(
-    w: jax.Array,
-    m: jax.Array,
-    x: jax.Array,
-    xp: jax.Array,
-    coef: jax.Array,
-    *,
-    bm: int = 128,
-    bk: int = 128,
-    bf: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused masked round Y = a*(W_eff@X) + b*X + c*Xp, operands pre-padded.
-
-    ``m`` is this round's (N, N) 0/1 activity mask (1 on the diagonal). Pad
-    the mask region beyond the real nodes with zeros — padded W entries are
-    zero either way. Shape management lives in ``repro.kernels.ops``.
-    """
-    n, k = w.shape
-    k2, f = x.shape
-    if k != k2 or x.shape != xp.shape or m.shape != w.shape:
-        raise ValueError(
-            f"shape mismatch: W {w.shape}, M {m.shape}, X {x.shape}, Xp {xp.shape}"
-        )
-    if n % bm or k % bk or f % bf:
-        raise ValueError(f"shapes ({n},{k},{f}) not multiples of tiles ({bm},{bk},{bf})")
-    nk = k // bk
-    grid = (n // bm, f // bf, nk)
-    return pl.pallas_call(
-        functools.partial(gossip_round_masked_kernel, nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda i, j, kk: (0, 0)),
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bf), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
-        interpret=interpret,
-    )(coef, w, m, x, x, xp)
-
-
 def gossip_round_masked_batched_kernel(nk: int, coef_ref, w_ref, m_ref, xk_ref,
                                        xi_ref, xp_ref, y_ref):
     """Batched-grid masked body: blocks carry a leading length-1 graph dim."""
@@ -279,15 +152,16 @@ def gossip_round_masked_batched_kernel(nk: int, coef_ref, w_ref, m_ref, xk_ref,
     wm = w * m_ref[0]
     drop = jnp.sum(w - wm, axis=1, keepdims=True)
     y_ref[0] += (
-        jnp.dot(wm, xk_ref[0], preferred_element_type=jnp.float32)
+        jnp.dot(wm, xk_ref[0], preferred_element_type=jnp.float32,
+                precision=_HIGHEST)
         + drop * xi_ref[0]
     )
 
     @pl.when(k == nk - 1)
     def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
+        a = coef_ref[0, 0, 0]
+        b = coef_ref[0, 0, 1]
+        c = coef_ref[0, 0, 2]
         y_ref[...] = a * y_ref[...] + b * xi_ref[...] + c * xp_ref[...]
 
 
@@ -326,7 +200,7 @@ def gossip_round_masked_batched_pallas(
         functools.partial(gossip_round_masked_batched_kernel, nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, kk: (gg, 0)),
+            COEF_SPEC,
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
             pl.BlockSpec((1, bk, bf), lambda gg, i, j, kk: (gg, kk, j)),
@@ -336,7 +210,7 @@ def gossip_round_masked_batched_pallas(
         out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, ws, ms, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), ws, ms, xs, xs, xps)
 
 
 # ---------------------------------------------------------------------------
@@ -374,15 +248,16 @@ def gossip_round_sender_masked_batched_kernel(nk: int, coef_ref, w_ref, wt_ref,
     # restricted to senders in the current K block.
     dropc = jnp.sum(wt_ref[0] * (1.0 - mt_ref[0]), axis=0)
     y_ref[0] += (
-        jnp.dot(wm, xk_ref[0], preferred_element_type=jnp.float32)
+        jnp.dot(wm, xk_ref[0], preferred_element_type=jnp.float32,
+                precision=_HIGHEST)
         + dropc[:, None] * xi_ref[0]
     )
 
     @pl.when(k == nk - 1)
     def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
+        a = coef_ref[0, 0, 0]
+        b = coef_ref[0, 0, 1]
+        c = coef_ref[0, 0, 2]
         y_ref[...] = a * y_ref[...] + b * xi_ref[...] + c * xp_ref[...]
 
 
@@ -425,7 +300,7 @@ def gossip_round_sender_masked_batched_pallas(
         functools.partial(gossip_round_sender_masked_batched_kernel, nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, kk: (gg, 0)),
+            COEF_SPEC,
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
             pl.BlockSpec((1, bk, bm), lambda gg, i, j, kk: (gg, kk, i)),
             pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
@@ -437,4 +312,4 @@ def gossip_round_sender_masked_batched_pallas(
         out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, ws, ws, ms, ms, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), ws, ws, ms, ms, xs, xs, xps)

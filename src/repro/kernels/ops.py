@@ -12,7 +12,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
-import inspect
 import os
 
 import jax
@@ -27,16 +26,12 @@ from .gossip_matvec import gossip_matvec_pallas
 from .gossip_round import (
     gossip_round_batched_pallas,
     gossip_round_masked_batched_pallas,
-    gossip_round_masked_pallas,
-    gossip_round_pallas,
     gossip_round_sender_masked_batched_pallas,
 )
 from .ref import ssd_chunk_ref
 from .segment_round import (
     segment_round_batched_pallas,
     segment_round_masked_batched_pallas,
-    segment_round_masked_pallas,
-    segment_round_pallas,
     segment_round_sender_masked_batched_pallas,
 )
 from .ssd_chunk import ssd_chunk_pallas
@@ -46,14 +41,13 @@ __all__ = [
     "batched_segment_round_prim",
     "build_ell",
     "consensus_update",
-    "cp_partition_count",
+    "ell_slot_major",
     "gossip_matvec",
     "gossip_round",
     "gossip_round_batched",
     "gossip_round_masked",
     "gossip_round_masked_batched",
     "round_tiles",
-    "segment_bn",
     "segment_round",
     "segment_tiles",
     "ssd_scan",
@@ -178,132 +172,6 @@ def round_tiles(n: int, f: int, g: int = 1, tune: bool = False):
     return autotune.get_tiles("round", n, f, g, bench=bench)
 
 
-# ---------------------------------------------------------------------------
-# custom_partitioning over G: the batched round kernels are embarrassingly
-# parallel over the ensemble axis — every operand (Ws, masks, ELL arrays,
-# states, coefs, bits) carries G as dim 0 and nothing crosses graphs. Without
-# a rule, GSPMD treats the pallas_call as an opaque custom call and
-# replicates it: every device would run the FULL (G, ...) grid. The wrappers
-# below declare "shard dim 0 however the operands are sharded, replicate the
-# rest", so the sweep engine's existing NamedSharding(mesh, P('data')) G
-# layout flows straight through — no shard_map, no replicated dispatch.
-# Dispatch skips the wrapper entirely on single-device processes (the
-# common CPU/test path).
-# ---------------------------------------------------------------------------
-
-_CP_PARTITION_CALLS = 0
-
-
-def cp_partition_count() -> int:
-    """How many times GSPMD invoked a round-kernel partition rule (tests)."""
-    return _CP_PARTITION_CALLS
-
-
-def reset_cp_partition_count() -> None:
-    """Zero the fired-counter. The counter is process-global; any test that
-    asserts on absolute values (rather than deltas) must reset it first or
-    an earlier multidevice test's compilations leak into the assertion."""
-    global _CP_PARTITION_CALLS
-    _CP_PARTITION_CALLS = 0
-
-
-@contextlib.contextmanager
-def cp_partition_calls():
-    """Scoped delta view of the fired-counter: yields a zero-arg callable
-    returning how many partition-rule invocations happened since entry.
-    Robust against interleaved suites — each scope measures its own delta,
-    so absolute counts never leak across assertions."""
-    start = _CP_PARTITION_CALLS
-    yield lambda: _CP_PARTITION_CALLS - start
-
-
-# Trace-time override for the single-device fast path below: the static
-# analyzer (repro.analysis.meshkernel) traces the engine on a one-device
-# host but must see the jaxpr a MESH run would lower — i.e. every batched
-# kernel behind its custom_partitioning wrapper — to verify no pallas_call
-# escapes unwrapped. Never set during real runs.
-_FORCE_MESH = contextvars.ContextVar("force_mesh_dispatch", default=False)
-
-
-@contextlib.contextmanager
-def force_mesh_dispatch():
-    """Make batched-round prim builders take the custom_partitioning path
-    regardless of ``jax.device_count()`` (static-analysis tracing only)."""
-    token = _FORCE_MESH.set(True)
-    try:
-        yield
-    finally:
-        _FORCE_MESH.reset(token)
-
-
-def _g_axis(arg_shapes):
-    """The mesh axis dim 0 is sharded over, from the first sharded operand."""
-    for a in arg_shapes:
-        s = getattr(a, "sharding", None)
-        if isinstance(s, NamedSharding) and len(s.spec) and s.spec[0] is not None:
-            return s.spec[0]
-    return None
-
-
-def _dim0_sharding(mesh, g_ax, ndim):
-    return NamedSharding(mesh, P(*((g_ax,) + (None,) * (ndim - 1))))
-
-
-def _batched_infer(mesh, arg_shapes, result_shape):
-    g_ax = _g_axis(arg_shapes)
-    return _dim0_sharding(mesh, g_ax, len(result_shape.shape))
-
-
-def _make_batched_partition(call):
-    def _partition(mesh, arg_shapes, result_shape):
-        global _CP_PARTITION_CALLS
-        _CP_PARTITION_CALLS += 1
-        g_ax = _g_axis(arg_shapes)
-        arg_shardings = tuple(
-            _dim0_sharding(mesh, g_ax, len(a.shape)) for a in arg_shapes)
-        out_sharding = _dim0_sharding(mesh, g_ax, len(result_shape.shape))
-
-        def lower_fn(*args):
-            return call(*args)
-
-        return mesh, lower_fn, out_sharding, arg_shardings
-
-    return _partition
-
-
-@functools.lru_cache(maxsize=None)
-def _round_cp(variant: str, bm: int, bk: int, bf: int, interpret: bool):
-    """custom_partitioning wrapper for one dense batched-kernel variant.
-
-    Cached per (variant, tiles, interpret) so a sweep's scan body reuses one
-    wrapped callable — custom_partitioning instances are identity-keyed in
-    the jaxpr, and rebuilding one per trace would defeat the jit cache.
-    No Shardy ``sharding_rule``: X's node axis is both contracted (W @ X)
-    and elementwise (the taps), which an einsum-factor rule cannot express;
-    the GSPMD callbacks fully describe the G-only partitioning.
-    """
-    kw = dict(bm=bm, bk=bk, bf=bf, interpret=interpret)
-    if variant == "plain":
-        def call(ws, xs, xps, coefs):
-            return gossip_round_batched_pallas(ws, xs, xps, coefs, **kw)
-    elif variant == "masked":
-        def call(ws, ms, xs, xps, coefs):
-            return gossip_round_masked_batched_pallas(ws, ms, xs, xps, coefs, **kw)
-    elif variant == "sender":
-        def call(ws, ms, xs, xps, coefs):
-            return gossip_round_sender_masked_batched_pallas(
-                ws, ms, xs, xps, coefs, **kw)
-    else:
-        raise ValueError(f"unknown dense round variant {variant!r}")
-    cp = custom_partitioning(call)
-    cp.def_partition(
-        partition=_make_batched_partition(call),
-        infer_sharding_from_operands=_batched_infer,
-        decode_shardings=True,
-    )
-    return cp
-
-
 def batched_round_prim(ws, *, bm: int = 128, bk: int = 128, bf: int = 512,
                        interpret: bool | None = None,
                        renorm: str = "receiver"):
@@ -324,10 +192,8 @@ def batched_round_prim(ws, *, bm: int = 128, bk: int = 128, bf: int = 512,
     must already be padded to the (bm, bk, bf) tiles — the sweep engine pads
     ONCE outside its scan (see ``repro.sweep.engine``).
 
-    On multi-device processes every kernel call goes through a
-    ``custom_partitioning`` wrapper that shards the G axis however the
-    operands are sharded (see ``_round_cp``), so the same prim serves both
-    the single-device jit and the mesh-sharded sweep.
+    Under a mesh the engine runs its whole scan inside ``shard_map`` over
+    the G axis, so the prim always sees one device's block of cells.
 
     ``coef`` is a traced per-CALL operand, never a compile-time constant:
     the kernels read it from memory each launch, so per-round coefficient
@@ -340,22 +206,15 @@ def batched_round_prim(ws, *, bm: int = 128, bk: int = 128, bf: int = 512,
         interpret = use_interpret()
     if renorm not in ("receiver", "sender"):
         raise ValueError(f"renorm must be receiver or sender, got {renorm!r}")
-    single = jax.device_count() == 1 and not _FORCE_MESH.get()
     kw = dict(bm=bm, bk=bk, bf=bf, interpret=interpret)
 
     def prim(x, xp, coef, m=None):
         if m is None:
-            if single:
-                return gossip_round_batched_pallas(ws, x, xp, coef, **kw)
-            return _round_cp("plain", bm, bk, bf, interpret)(ws, x, xp, coef)
+            return gossip_round_batched_pallas(ws, x, xp, coef, **kw)
         if renorm == "receiver":
-            if single:
-                return gossip_round_masked_batched_pallas(ws, m, x, xp, coef, **kw)
-            return _round_cp("masked", bm, bk, bf, interpret)(ws, m, x, xp, coef)
-        if single:
-            return gossip_round_sender_masked_batched_pallas(
-                ws, m, x, xp, coef, **kw)
-        return _round_cp("sender", bm, bk, bf, interpret)(ws, m, x, xp, coef)
+            return gossip_round_masked_batched_pallas(ws, m, x, xp, coef, **kw)
+        return gossip_round_sender_masked_batched_pallas(
+            ws, m, x, xp, coef, **kw)
 
     return prim
 
@@ -378,20 +237,14 @@ def gossip_round(w, x, xp, a, b, c):
     """
     if use_interpret():
         return consensus_update(gossip_matvec(w, x), x, xp, a, b, c)
-    n, f = w.shape[0], x.shape[1]
-    bm, bk, bf = round_tiles(n, f)
-    np_, fp_ = _round_up(n, max(bm, bk)), _round_up(f, bf)
-    wp = jnp.pad(w.astype(jnp.float32), ((0, np_ - n), (0, np_ - n)))
-    xpad = jnp.pad(x.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    xppad = jnp.pad(xp.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    coef = jnp.stack(
+    return gossip_round_batched(w[None], x[None], xp[None], _coef_row(a, b, c))[0]
+
+
+def _coef_row(a, b, c):
+    return jnp.stack(
         [jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32),
          jnp.asarray(c, jnp.float32)]
     ).reshape(1, 3)
-    y = gossip_round_pallas(
-        wp, xpad, xppad, coef, bm=bm, bk=bk, bf=bf, interpret=use_interpret()
-    )
-    return y[:n, :f]
 
 
 @jax.jit
@@ -424,21 +277,8 @@ def gossip_round_masked(w, m, x, xp, a, b, c):
     W_eff stays doubly stochastic. Mask padding is zeros — padded W entries
     are zero, so they contribute neither matvec nor dropped mass.
     """
-    n, f = w.shape[0], x.shape[1]
-    bm, bk, bf = round_tiles(n, f)
-    np_, fp_ = _round_up(n, max(bm, bk)), _round_up(f, bf)
-    wp = jnp.pad(w.astype(jnp.float32), ((0, np_ - n), (0, np_ - n)))
-    mp = jnp.pad(m.astype(jnp.float32), ((0, np_ - n), (0, np_ - n)))
-    xpad = jnp.pad(x.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    xppad = jnp.pad(xp.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    coef = jnp.stack(
-        [jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32),
-         jnp.asarray(c, jnp.float32)]
-    ).reshape(1, 3)
-    y = gossip_round_masked_pallas(
-        wp, mp, xpad, xppad, coef, bm=bm, bk=bk, bf=bf, interpret=use_interpret()
-    )
-    return y[:n, :f]
+    return gossip_round_masked_batched(
+        w[None], m[None], x[None], xp[None], _coef_row(a, b, c))[0]
 
 
 @jax.jit
@@ -481,15 +321,15 @@ def _segment_bench(n: int, f: int, g: int):
         np_, fp_ = _round_up(n, bm), _round_up(f, bf)
         if (np_, fp_) not in arrays:
             idx = jnp.arange(np_, dtype=jnp.int32)
-            nbrs = jnp.stack([(idx + 1) % np_, (idx - 1) % np_], axis=1)
+            nbrs = jnp.stack([(idx + 1) % np_, (idx - 1) % np_], axis=0)
             nbrs = jnp.broadcast_to(
-                jnp.pad(nbrs, ((0, 0), (0, bd - 2))), (gb, np_, bd))
+                jnp.pad(nbrs, ((0, bd - 2), (0, 0))), (gb, bd, np_))
             wgts = jnp.broadcast_to(
-                jnp.pad(jnp.full((np_, 2), 0.25, jnp.float32),
-                        ((0, 0), (0, bd - 2))), (gb, np_, bd))
+                jnp.pad(jnp.full((2, np_), 0.25, jnp.float32),
+                        ((0, bd - 2), (0, 0))), (gb, bd, np_))
             arrays[(np_, fp_)] = (
                 nbrs, wgts,
-                jnp.full((gb, np_, 1), 0.5, jnp.float32),
+                jnp.full((gb, 1, np_), 0.5, jnp.float32),
                 jnp.ones((gb, np_, fp_), jnp.float32),
                 jnp.ones((gb, 3), jnp.float32),
             )
@@ -510,37 +350,6 @@ def segment_tiles(n: int, f: int, g: int = 1, tune: bool = False):
     """
     bench = _segment_bench(n, f, g) if tune else None
     return autotune.get_tiles("segment", n, f, g, bench=bench)
-
-
-_SEGMENT_VMEM_BUDGET = 8 * 1024 * 1024  # resident X source block, bytes
-
-
-def segment_bn(n: int, bm: int, bf: int) -> tuple[int, int]:
-    """VMEM tiling policy for the segment kernels' resident X source block.
-
-    Returns (bn, n_padded): the source-row block size and the padded node
-    count (a multiple of both bn and bm). The kernels gather from a (bn, bf)
-    X block held in VMEM; bn * bf * 4 bytes must fit the budget
-    (``REPRO_SEGMENT_VMEM_BUDGET`` overrides the 8 MiB default). Small
-    problems get bn = N (one resident block, S = 1 — bitwise identical to
-    the historical un-tiled kernel); past the cap, bn is the budget-sized
-    multiple of bm that wastes the least padding. bn is a deliberate
-    POLICY parameter, not an autotuned one: splitting the gather reduction
-    reorders float accumulation, so tuning it would break the autotuner's
-    bit-identicality contract.
-    """
-    budget = int(os.environ.get(
-        "REPRO_SEGMENT_VMEM_BUDGET", _SEGMENT_VMEM_BUDGET))
-    cap_rows = max(bm, (budget // (bf * 4)) // bm * bm)
-    n_bm = _round_up(n, bm)
-    if n_bm <= cap_rows:
-        return n_bm, n_bm
-    best = None
-    for bn in range(cap_rows, 0, -bm):
-        n_pad = _round_up(n_bm, bn)
-        if best is None or n_pad < best[1]:
-            best = (bn, n_pad)
-    return best
 
 
 def build_ell(edges, edge_w, diag_w, n: int, edge_w_rev=None):
@@ -566,6 +375,7 @@ def build_ell(edges, edge_w, diag_w, n: int, edge_w_rev=None):
     the slot mirrors, so the masked kernels gather one (E,) bits row
     instead of an (N, N) mask; ``wrev`` feeds the sender-renorm masked
     kernel's column dropped-mass sum (for symmetric bases wrev == wgt).
+    ``ell_slot_major`` turns these into the kernels' operand layout.
     """
     import numpy as np
 
@@ -597,82 +407,51 @@ def build_ell(edges, edge_w, diag_w, n: int, edge_w_rev=None):
     return nbr, wgt, wrev, slot, diag
 
 
+def ell_slot_major(*arrays):
+    """``build_ell``'s row-major (..., N, D) / (..., N, 1) arrays -> the
+    kernels' slot-major (..., D, N) / (..., 1, N) layout, node axis on lanes
+    (``kernels.segment_round``). Works on numpy and jax arrays, any leading
+    (G) dims."""
+    return tuple(a.swapaxes(-1, -2) for a in arrays)
+
+
 @jax.jit
 def segment_round(nbr, wgt, slot, diag, x, xp, a, b, c, bits=None):
     """One fused sparse round on a single graph, auto-padded to kernel tiles.
 
-    ELL operands from ``build_ell``; X/Xp (N, F); a/b/c scalars; ``bits``
-    an optional (E,) 0/1 activity row for this round (None = all edges up).
-    Padding is exact: padded rows have diag 0 and x 0, padded slots have
-    weight 0, padded bits columns are unreferenced.
+    Row-major ELL operands from ``build_ell``; X/Xp (N, F); a/b/c scalars;
+    ``bits`` an optional (E,) 0/1 activity row for this round (None = all
+    edges up). Runs the batched kernels at G = 1. Padding is exact: padded
+    rows have diag 0 and x 0, padded slots have weight 0.
     """
     n, f = x.shape
     d = nbr.shape[1]
     bm, bd, bf = segment_tiles(n, f)
+    bd = min(bd, d)
     np_, dp_, fp_ = _round_up(n, bm), _round_up(d, bd), _round_up(f, bf)
-    nbrp = jnp.pad(nbr, ((0, np_ - n), (0, dp_ - d)))
-    wgtp = jnp.pad(wgt.astype(jnp.float32), ((0, np_ - n), (0, dp_ - d)))
-    diagp = jnp.pad(diag.astype(jnp.float32), ((0, np_ - n), (0, 0)))
-    xpad = jnp.pad(x.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    xppad = jnp.pad(xp.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))
-    coef = jnp.stack(
-        [jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32),
-         jnp.asarray(c, jnp.float32)]
-    ).reshape(1, 3)
+    pad_nd = lambda t: jnp.pad(t, ((0, np_ - n), (0, dp_ - d)))[None]
+    nbrs, wgts, slots, diags = ell_slot_major(
+        pad_nd(nbr), pad_nd(wgt.astype(jnp.float32)), pad_nd(slot),
+        jnp.pad(diag.astype(jnp.float32), ((0, np_ - n), (0, 0)))[None])
+    xpad = jnp.pad(x.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))[None]
+    xppad = jnp.pad(xp.astype(jnp.float32), ((0, np_ - n), (0, fp_ - f)))[None]
+    coef = _coef_row(a, b, c)
+    kw = dict(bm=bm, bd=bd, bf=bf, interpret=use_interpret())
     if bits is None:
-        y = segment_round_pallas(
-            nbrp, wgtp, diagp, xpad, xppad, coef,
-            bm=bm, bd=bd, bf=bf, interpret=use_interpret())
+        y = segment_round_batched_pallas(
+            nbrs, wgts, diags, xpad, xppad, coef, **kw)
     else:
-        slotp = jnp.pad(slot, ((0, np_ - n), (0, dp_ - d)))
-        e = bits.shape[0]
-        bitsp = jnp.pad(bits.astype(jnp.float32),
-                        (0, _round_up(max(e, 1), 128) - e)).reshape(1, -1)
-        y = segment_round_masked_pallas(
-            nbrp, wgtp, slotp, diagp, bitsp, xpad, xppad, coef,
-            bm=bm, bd=bd, bf=bf, interpret=use_interpret())
-    return y[:n, :f]
-
-
-@functools.lru_cache(maxsize=None)
-def _seg_cp(variant: str, bm: int, bd: int, bf: int, bn: int | None,
-            interpret: bool):
-    """custom_partitioning wrapper for one ELLPACK batched-kernel variant.
-
-    Same G-only partitioning contract as ``_round_cp``: every operand
-    (ELL arrays, bits, states, coefs) leads with the ensemble axis, nothing
-    crosses graphs, so dim 0 shards and everything else stays whole.
-    """
-    kw = dict(bm=bm, bd=bd, bf=bf, bn=bn, interpret=interpret)
-    if variant == "plain":
-        def call(nbrs, wgts, diags, xs, xps, coefs):
-            return segment_round_batched_pallas(
-                nbrs, wgts, diags, xs, xps, coefs, **kw)
-    elif variant == "masked":
-        def call(nbrs, wgts, slots, diags, bits, xs, xps, coefs):
-            return segment_round_masked_batched_pallas(
-                nbrs, wgts, slots, diags, bits, xs, xps, coefs, **kw)
-    elif variant == "sender":
-        def call(nbrs, wgts, wrevs, slots, diags, bits, xs, xps, coefs):
-            return segment_round_sender_masked_batched_pallas(
-                nbrs, wgts, wrevs, slots, diags, bits, xs, xps, coefs, **kw)
-    else:
-        raise ValueError(f"unknown segment round variant {variant!r}")
-    cp = custom_partitioning(call)
-    cp.def_partition(
-        partition=_make_batched_partition(call),
-        infer_sharding_from_operands=_batched_infer,
-        decode_shardings=True,
-    )
-    return cp
+        y = segment_round_masked_batched_pallas(
+            nbrs, wgts, slots, diags, bits.astype(jnp.float32)[None],
+            xpad, xppad, coef, **kw)
+    return y[0, :n, :f]
 
 
 def batched_segment_round_prim(nbrs, wgts, slots, diags, *, wrevs=None,
                                bm: int = 128, bd: int = 8, bf: int = 128,
-                               bn: int | None = None,
                                interpret: bool | None = None,
                                renorm: str = "receiver"):
-    """Sparse fused-round primitive over pre-padded (Gp, N, D) ELL slices.
+    """Sparse fused-round primitive over pre-padded slot-major ELL slices.
 
     The sparse-layout counterpart of ``batched_round_prim`` — the returned
 
@@ -683,14 +462,12 @@ def batched_segment_round_prim(nbrs, wgts, slots, diags, *, wrevs=None,
     (Gp, E) compressed bits rows (NOT an (N, N) mask — the sparse path never
     builds one). ``renorm`` selects where a dropped edge's mass returns
     ("receiver" = row renorm; "sender" = column renorm, which requires the
-    (Gp, N, D) reverse weights ``wrevs`` from ``build_ell``). ``bn`` tiles
-    the kernels' resident X source block over N for the VMEM cap (see
-    ``segment_bn``; None = one full-N block). Operands must already be
-    padded to the (bm, bd, bf) tiles — and N to a bn multiple — by the
-    sweep engine, ONCE outside its scan.
+    reverse weights ``wrevs``). ELL operands are in the kernel layout
+    (``ell_slot_major``: nbrs/wgts/wrevs/slots (Gp, D, N), diags (Gp, 1, N))
+    and must already be padded to the (bm, bd, bf) tiles by the sweep engine,
+    ONCE outside its scan.
 
-    Multi-device processes route every call through a G-axis
-    ``custom_partitioning`` wrapper (``_seg_cp``), mirroring the dense prim.
+    Under a mesh it sees one device's block of cells, like the dense prim.
     """
     if interpret is None:
         interpret = use_interpret()
@@ -698,27 +475,17 @@ def batched_segment_round_prim(nbrs, wgts, slots, diags, *, wrevs=None,
         raise ValueError(f"renorm must be receiver or sender, got {renorm!r}")
     if renorm == "sender" and wrevs is None:
         raise ValueError("renorm='sender' needs the wrevs ELL array")
-    single = jax.device_count() == 1 and not _FORCE_MESH.get()
-    kw = dict(bm=bm, bd=bd, bf=bf, bn=bn, interpret=interpret)
+    kw = dict(bm=bm, bd=bd, bf=bf, interpret=interpret)
 
     def prim(x, xp, coef, m=None):
         if m is None:
-            if single:
-                return segment_round_batched_pallas(
-                    nbrs, wgts, diags, x, xp, coef, **kw)
-            return _seg_cp("plain", bm, bd, bf, bn, interpret)(
-                nbrs, wgts, diags, x, xp, coef)
+            return segment_round_batched_pallas(
+                nbrs, wgts, diags, x, xp, coef, **kw)
         if renorm == "receiver":
-            if single:
-                return segment_round_masked_batched_pallas(
-                    nbrs, wgts, slots, diags, m, x, xp, coef, **kw)
-            return _seg_cp("masked", bm, bd, bf, bn, interpret)(
-                nbrs, wgts, slots, diags, m, x, xp, coef)
-        if single:
-            return segment_round_sender_masked_batched_pallas(
-                nbrs, wgts, wrevs, slots, diags, m, x, xp, coef, **kw)
-        return _seg_cp("sender", bm, bd, bf, bn, interpret)(
-            nbrs, wgts, wrevs, slots, diags, m, x, xp, coef)
+            return segment_round_masked_batched_pallas(
+                nbrs, wgts, slots, diags, m, x, xp, coef, **kw)
+        return segment_round_sender_masked_batched_pallas(
+            nbrs, wgts, wrevs, slots, diags, m, x, xp, coef, **kw)
 
     return prim
 
@@ -818,19 +585,15 @@ def _ssd_partition(mesh, arg_shapes, result_shape):
 
 # Shardy rule: n (batch*chunks) and h (heads) are parallel factors; the
 # chunk/state/head_dim factors stay whole per program; g (groups) is
-# replicated (its head mapping happens inside the kernel grid). jaxlib builds
-# that predate Shardy's custom_partitioning hook don't accept the kwarg —
-# GSPMD then relies on the infer/partition callbacks alone.
-_def_partition_kwargs = dict(
+# replicated (its head mapping happens inside the kernel grid).
+_ssd_chunk_cp.def_partition(
     partition=_ssd_partition,
     infer_sharding_from_operands=_ssd_infer,
     decode_shardings=True,
-)
-if "sharding_rule" in inspect.signature(custom_partitioning.def_partition).parameters:
-    _def_partition_kwargs["sharding_rule"] = (
+    sharding_rule=(
         "n h l p, n h o l, n g l s, n g l s -> n h l p, n h s p, n h o l, n h o q"
-    )
-_ssd_chunk_cp.def_partition(**_def_partition_kwargs)
+    ),
+)
 
 
 def _ssd_core(x, a, b, c, h0, chunk: int, use_kernel: bool):

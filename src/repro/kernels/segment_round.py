@@ -3,46 +3,49 @@
     Y = a * (W_eff @ X) + b * X + c * Xp
 
 where W is never materialized: each cell stores an ELLPACK (padded per-row
-neighbor list) view of its canonical edge list —
+neighbor list) view of its canonical edge list, slot-major —
 
-    nbr  (N, D) int32   neighbor node index per row slot
-    wgt  (N, D) f32     edge weight per slot (0 on padding slots)
-    slot (N, D) int32   undirected-edge id per slot (RoundMasks bits column)
-    diag (N, 1) f32     W's diagonal
+    nbr  (D, N) int32   neighbor node index per slot and row
+    wgt  (D, N) f32     edge weight per slot (0 on padding slots)
+    wrev (D, N) f32     reverse weight W[nbr, i] of the slot's edge
+    slot (D, N) int32   undirected-edge id per slot (RoundMasks bits column)
+    diag (1, N) f32     W's diagonal
 
-and one round is a gather + weighted segment reduction:
+(``repro.kernels.ops.ell_slot_major`` turns ``build_ell``'s row-major
+arrays into this layout) and one round is a gather + weighted slot sum:
 
-    y[i] = a * (diag[i] * x[i] + sum_d wgt[i,d] * x[nbr[i,d]]) + b*x[i] + c*xp[i]
+    y[i] = a * (diag[i] * x[i] + sum_d wgt[d,i] * x[nbr[d,i]]) + b*x[i] + c*xp[i]
 
-Grid layout mirrors ``gossip_round.py``: (N/bm, F/bf, N/bn, D/bd) with the
-slot axis D innermost and an optional source-row block axis S = N/bn above
-it — the output index map ignores (s, d), so Pallas keeps the (bm, bf) block
-resident across the whole reduction, initializing at s == d == 0 and applying
-the FMA taps (and the diagonal term) on the final (s, d) step. The masked
-variants apply this round's 0/1 edge-activity bits per slot with the
-mass-preserving rule: a dropped slot's weight returns to its row's diagonal,
-so W_eff stays doubly stochastic (identical semantics to the dense masked
-kernel; the per-cell bits row is gathered through ``slot``). The sender
-variant returns dropped mass to the *sender's* diagonal instead (column
-renormalization), which needs the reverse weight ``wrev[i, d] =
-W[nbr[i,d], i]`` of each slot's edge — the column-stochastic family
-(push_sum / ratio_consensus) stays exactly column-stochastic under masking.
+The gather runs in XLA ahead of the kernel: ``x[nbr]`` becomes a (D, N, F)
+slot-major stack of neighbor rows (and, for masked rounds, ``bits[slot]`` a
+(D, N) live-flag stack), so every kernel operand is a rectangular tile —
+an arbitrary-row gather from a VMEM block does not lower on the TPU, and a
+resident source block could not hold a million-node state anyway. The
+kernel then does the weighted slot reduction, the masked-drop bookkeeping
+and the two-tap FMA in one pass over the gathered rows. Per-slot and
+per-node vectors keep the node axis on lanes: an (N, 1) array is stored
+lane-padded to (N, 128) in HBM, which at a million nodes is gigabytes; the
+kernel moves each (bd, bm) tile's nodes onto sublanes itself.
 
-VMEM policy: the gather targets arbitrary rows of X, so the kernel holds a
-(bn, bf) source block resident and masks each slot tile to the rows that
-live in the current block (``bn`` defaults to the full padded N — one
-resident block, no masking overhead, bitwise identical to the historical
-un-tiled kernel). When N * bf * 4 bytes would blow the VMEM cap, callers
-pass bn < N and the kernel sweeps S = N/bn source blocks per output tile:
-per-slot selection ``bn <= nbr < bn + bn`` zeroes out-of-block weights, so
-each slot contributes exactly once across the S sweep. See
-``repro.kernels.ops.segment_bn`` for the budget policy
-(REPRO_SEGMENT_VMEM_BUDGET).
+Grid: (G, N/bm, F/bf, D/bd) with the slot axis innermost — the output index
+map ignores d, so Pallas keeps the (bm, bf) block resident across the
+reduction, initializing at d == 0 and applying the FMA taps (and the
+diagonal term) on the final d step. Blocks are (bd, bm), (1, bm) or
+(bm, bf) tiles with bm a multiple of 128 and bd a multiple of 8 or the whole
+padded degree; the per-cell (a, b, c) row rides in SMEM.
 
-Padding invariants (``repro.kernels.ops`` pads): padded row slots carry
-wgt = 0 *and* wrev = 0 (inert in both the reduction and the dropped-mass
-sums, whatever nbr/slot say), padded rows carry diag = 0 and x = 0, padded
-bits columns are never referenced by a real slot.
+The masked variants apply this round's 0/1 edge-activity bits per slot with
+the mass-preserving rule: a dropped slot's weight returns to its row's
+diagonal, so W_eff stays doubly stochastic (identical semantics to the dense
+masked kernel). The sender variant returns dropped mass to the *sender's*
+diagonal instead (column renormalization), through the reverse weight of
+each slot's edge — the column-stochastic family (push_sum /
+ratio_consensus) stays exactly column-stochastic under masking.
+
+Padding invariants (``repro.kernels.ops`` pads): padded slots carry wgt = 0
+*and* wrev = 0 (inert in both the reduction and the dropped-mass sums,
+whatever nbr/slot say, as long as they index in range), padded rows carry
+diag = 0 and x = 0, padded bits columns are never referenced by a real slot.
 """
 from __future__ import annotations
 
@@ -52,13 +55,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .gossip_round import COEF_SPEC
+
 __all__ = [
-    "segment_round_kernel",
-    "segment_round_pallas",
+    "gather_rows",
+    "gather_bits",
     "segment_round_batched_kernel",
     "segment_round_batched_pallas",
-    "segment_round_masked_kernel",
-    "segment_round_masked_pallas",
     "segment_round_masked_batched_kernel",
     "segment_round_masked_batched_pallas",
     "segment_round_sender_masked_batched_kernel",
@@ -66,125 +69,77 @@ __all__ = [
 ]
 
 
-def _gather_rows(xf, nbr):
-    """(bn, bf) x block, (bm, bd) local indices -> (bm, bd, bf) gathered rows."""
-    bm, bd = nbr.shape
-    return jnp.take(xf, nbr.reshape(-1), axis=0).reshape(bm, bd, -1)
+def gather_rows(xs, nbrs):
+    """(G, N, F) states, (G, D, N) neighbor ids -> (G, D, N, F) neighbor rows."""
+    return jax.vmap(lambda x, nb: jnp.take(x, nb, axis=0, mode="clip"))(xs, nbrs)
 
 
-def _block_select(nbr, s, bn):
-    """0/1 mask of slots whose neighbor lives in source block s, + local ids."""
-    base = s * bn
-    sel = ((nbr >= base) & (nbr < base + bn)).astype(jnp.float32)
-    local = jnp.clip(nbr - base, 0, bn - 1)
-    return sel, local
+def gather_bits(bits, slots):
+    """(G, E) activity rows, (G, D, N) edge ids -> (G, D, N) live flags."""
+    return jax.vmap(lambda b, s: jnp.take(b, s, axis=0, mode="clip"))(bits, slots)
 
 
-def _check_tiles(n, dmax, f, bm, bd, bf, bn):
-    if n % bm or dmax % bd or f % bf or n % bn:
+def _check(g, n, dmax, f, bm, bd, bf, coefs, diags, slot_arrays, state_arrays):
+    bad = (coefs.shape != (g, 3) or diags.shape != (g, 1, n)
+           or any(a.shape != (g, dmax, n) for a in slot_arrays)
+           or any(a.shape != (g, n, f) for a in state_arrays))
+    if bad:
         raise ValueError(
-            f"shapes ({n},{dmax},{f}) not multiples of tiles ({bm},{bd},{bf},{bn})")
+            "shape mismatch: " + ", ".join(
+                str(a.shape) for a in (*slot_arrays, diags, *state_arrays,
+                                       coefs)))
+    if n % bm or dmax % bd or f % bf:
+        raise ValueError(
+            f"shapes ({n},{dmax},{f}) not multiples of tiles ({bm},{bd},{bf})")
 
 
-def segment_round_kernel(ns: int, nd: int, bn: int, coef_ref, nbr_ref, wgt_ref,
-                         diag_ref, xf_ref, xi_ref, xp_ref, y_ref):
-    """Accumulate one bd-slot gather partial; diagonal + FMA on the last step."""
-    s = pl.program_id(2)
+def _specs(bm, bd, bf, n_slot_ops):
+    """coef (SMEM), n_slot_ops (1, bd, bm) slot operands, the (1, bd, bm, bf)
+    gathered rows, diag, x, xp; out (1, bm, bf)."""
+    slot_tile = pl.BlockSpec((1, bd, bm), lambda gg, i, j, d: (gg, d, i))
+    tile = pl.BlockSpec((1, bm, bf), lambda gg, i, j, d: (gg, i, j))
+    in_specs = [
+        COEF_SPEC,
+        *([slot_tile] * n_slot_ops),
+        pl.BlockSpec((1, bd, bm, bf), lambda gg, i, j, d: (gg, d, i, j)),
+        pl.BlockSpec((1, 1, bm), lambda gg, i, j, d: (gg, 0, i)),
+        tile,
+        tile,
+    ]
+    return in_specs, tile
+
+
+def _rows(v):
+    """(bd, bm) slot tile, nodes on lanes -> (bd, bm, 1), nodes on sublanes."""
+    return v[:, :, None]
+
+
+def _fma(coef_ref, diag_ref, xi_ref, xp_ref, y_ref):
+    a = coef_ref[0, 0, 0]
+    b = coef_ref[0, 0, 1]
+    c = coef_ref[0, 0, 2]
+    xi = xi_ref[0]
+    diag = jnp.transpose(diag_ref[0])                     # (bm, 1)
+    y_ref[0] = a * (y_ref[0] + diag * xi) + b * xi + c * xp_ref[0]
+
+
+def segment_round_batched_kernel(nd: int, coef_ref, wgt_ref, xg_ref, diag_ref,
+                                 xi_ref, xp_ref, y_ref):
+    """Accumulate one bd-slot weighted partial; diagonal + FMA on the last step."""
     d = pl.program_id(3)
 
-    @pl.when((s == 0) & (d == 0))
+    @pl.when(d == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    nbr = nbr_ref[...]
-    sel, local = _block_select(nbr, s, bn)
-    gathered = _gather_rows(xf_ref[...], local)
-    y_ref[...] += jnp.sum((wgt_ref[...] * sel)[..., None] * gathered, axis=1)
+    y_ref[0] += jnp.sum(_rows(wgt_ref[0]) * xg_ref[0], axis=0)
 
-    @pl.when((s == ns - 1) & (d == nd - 1))
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        xi = xi_ref[...]
-        y_ref[...] = a * (y_ref[...] + diag_ref[...] * xi) + b * xi + c * xp_ref[...]
+    @pl.when(d == nd - 1)
+    def _last():
+        _fma(coef_ref, diag_ref, xi_ref, xp_ref, y_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "bn", "interpret"))
-def segment_round_pallas(
-    nbr: jax.Array,
-    wgt: jax.Array,
-    diag: jax.Array,
-    x: jax.Array,
-    xp: jax.Array,
-    coef: jax.Array,
-    *,
-    bm: int = 128,
-    bd: int = 8,
-    bf: int = 128,
-    bn: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused sparse Y = a*(W@X) + b*X + c*Xp, operands pre-padded.
-
-    nbr/wgt (N, D), diag (N, 1), X/Xp (N, F), coef (1, 3) traced. ``bn``
-    (default: full N) tiles the resident X source block over N for the VMEM
-    cap. Shape management lives in ``repro.kernels.ops.segment_round``.
-    """
-    n, dmax = nbr.shape
-    n2, f = x.shape
-    if n != n2 or x.shape != xp.shape or wgt.shape != nbr.shape \
-            or diag.shape != (n, 1):
-        raise ValueError(f"shape mismatch: nbr {nbr.shape}, wgt {wgt.shape}, "
-                         f"diag {diag.shape}, X {x.shape}, Xp {xp.shape}")
-    bn = n if bn is None else bn
-    _check_tiles(n, dmax, f, bm, bd, bf, bn)
-    ns, nd = n // bn, dmax // bd
-    grid = (n // bm, f // bf, ns, nd)
-    return pl.pallas_call(
-        functools.partial(segment_round_kernel, ns, nd, bn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda i, j, s, d: (0, 0)),
-            pl.BlockSpec((bm, bd), lambda i, j, s, d: (i, d)),
-            pl.BlockSpec((bm, bd), lambda i, j, s, d: (i, d)),
-            pl.BlockSpec((bm, 1), lambda i, j, s, d: (i, 0)),
-            pl.BlockSpec((bn, bf), lambda i, j, s, d: (s, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
-        interpret=interpret,
-    )(coef, nbr, wgt, diag, x, x, xp)
-
-
-def segment_round_batched_kernel(ns: int, nd: int, bn: int, coef_ref, nbr_ref,
-                                 wgt_ref, diag_ref, xf_ref, xi_ref, xp_ref,
-                                 y_ref):
-    """Batched-grid body: blocks carry a leading length-1 graph dim."""
-    s = pl.program_id(3)
-    d = pl.program_id(4)
-
-    @pl.when((s == 0) & (d == 0))
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    nbr = nbr_ref[0]
-    sel, local = _block_select(nbr, s, bn)
-    gathered = _gather_rows(xf_ref[0], local)
-    y_ref[0] += jnp.sum((wgt_ref[0] * sel)[..., None] * gathered, axis=1)
-
-    @pl.when((s == ns - 1) & (d == nd - 1))
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        xi = xi_ref[...]
-        y_ref[...] = a * (y_ref[...] + diag_ref[...] * xi) + b * xi + c * xp_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "interpret"))
 def segment_round_batched_pallas(
     nbrs: jax.Array,
     wgts: jax.Array,
@@ -196,191 +151,63 @@ def segment_round_batched_pallas(
     bm: int = 128,
     bd: int = 8,
     bf: int = 128,
-    bn: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused sparse round over a stacked ensemble.
+    """Fused sparse round over a stacked ensemble, operands pre-padded.
 
-    nbrs/wgts (G, N, D), diags (G, N, 1), Xs/Xps (G, N, F), coefs (G, 3):
-    grid (G, N/bm, F/bf, N/bn, D/bd), each graph g reads its own ELL slices
-    and (a, b, c) row — one launch covers the whole sparse sweep grid.
+    nbrs/wgts (G, D, N), diags (G, 1, N), Xs/Xps (G, N, F), coefs (G, 3):
+    grid (G, N/bm, F/bf, D/bd), each graph g reads its own ELL
+    slices and (a, b, c) row — one launch covers the whole sparse sweep grid.
     """
-    g, n, dmax = nbrs.shape
-    g2, n2, f = xs.shape
-    if g != g2 or n != n2 or xs.shape != xps.shape or coefs.shape != (g, 3) \
-            or wgts.shape != nbrs.shape or diags.shape != (g, n, 1):
-        raise ValueError(
-            f"shape mismatch: nbrs {nbrs.shape}, wgts {wgts.shape}, "
-            f"diags {diags.shape}, Xs {xs.shape}, coefs {coefs.shape}")
-    bn = n if bn is None else bn
-    _check_tiles(n, dmax, f, bm, bd, bf, bn)
-    ns, nd = n // bn, dmax // bd
-    grid = (g, n // bm, f // bf, ns, nd)
+    g, n, f = xs.shape
+    dmax = nbrs.shape[1]
+    _check(g, n, dmax, f, bm, bd, bf, coefs, diags, (nbrs, wgts), (xs, xps))
+    in_specs, out_spec = _specs(bm, bd, bf, 1)
     return pl.pallas_call(
-        functools.partial(segment_round_batched_kernel, ns, nd, bn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, s, d: (gg, 0)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, 1), lambda gg, i, j, s, d: (gg, i, 0)),
-            pl.BlockSpec((1, bn, bf), lambda gg, i, j, s, d: (gg, s, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
+        functools.partial(segment_round_batched_kernel, dmax // bd),
+        grid=(g, n // bm, f // bf, dmax // bd),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, nbrs, wgts, diags, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), wgts, gather_rows(xs, nbrs), diags, xs, xps)
 
 
 # ---------------------------------------------------------------------------
 # Masked variants: per-round edge-activity bits applied INSIDE the kernel.
 #
-#     wt[i, d] = wgt[i, d] * bits[slot[i, d]]          (this round's live edges)
-#     drop[i]  = sum_d (wgt[i, d] - wt[i, d])          (mass back to the diagonal)
-#     y[i]     = a*( (diag[i]+drop[i])*x[i] + sum_d wt[i,d]*x[nbr[i,d]] )
+#     wt[d, i] = wgt[d, i] * bits[slot[d, i]]          (this round's live edges)
+#     drop[i]  = sum_d (wgt[d, i] - wt[d, i])          (mass back to the diagonal)
+#     y[i]     = a*( (diag[i]+drop[i])*x[i] + sum_d wt[d,i]*x[nbr[d,i]] )
 #                + b*x[i] + c*xp[i]
 #
 # Exactly the dense masked kernel's mass-preserving rule, evaluated per slot:
 # the compressed (G, E) bits row replaces the (G, N, N) mask expansion, so
-# the sparse dynamic sweep never materializes a mask matrix at all. Under
-# N-tiling the drop term is added on the s == 0 sweep only — every slot's
-# dropped mass is counted exactly once.
+# the sparse dynamic sweep never materializes a mask matrix at all.
 # ---------------------------------------------------------------------------
 
 
-def segment_round_masked_kernel(ns: int, nd: int, bn: int, coef_ref, bits_ref,
-                                nbr_ref, wgt_ref, slot_ref, diag_ref, xf_ref,
-                                xi_ref, xp_ref, y_ref):
-    """Masked gather partial + dropped-mass return per slot tile."""
-    s = pl.program_id(2)
+def segment_round_masked_batched_kernel(nd: int, coef_ref, wgt_ref, live_ref,
+                                        xg_ref, diag_ref, xi_ref, xp_ref,
+                                        y_ref):
+    """Masked weighted partial + dropped-mass return per slot tile."""
     d = pl.program_id(3)
 
-    @pl.when((s == 0) & (d == 0))
+    @pl.when(d == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    w = wgt_ref[...]
-    live = jnp.take(bits_ref[0], slot_ref[...].reshape(-1)).reshape(w.shape)
-    wt = w * live
-    nbr = nbr_ref[...]
-    sel, local = _block_select(nbr, s, bn)
-    gathered = _gather_rows(xf_ref[...], local)
-    contrib = jnp.sum((wt * sel)[..., None] * gathered, axis=1)
+    w = _rows(wgt_ref[0])
+    wt = w * _rows(live_ref[0])
+    y_ref[0] += (jnp.sum(wt * xg_ref[0], axis=0)
+                 + jnp.sum(w - wt, axis=0) * xi_ref[0])
 
-    @pl.when(s == 0)
-    def _with_drop():
-        drop = jnp.sum(w - wt, axis=1, keepdims=True)
-        y_ref[...] += contrib + drop * xi_ref[...]
-
-    @pl.when(s > 0)
-    def _partial():
-        y_ref[...] += contrib
-
-    @pl.when((s == ns - 1) & (d == nd - 1))
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        xi = xi_ref[...]
-        y_ref[...] = a * (y_ref[...] + diag_ref[...] * xi) + b * xi + c * xp_ref[...]
+    @pl.when(d == nd - 1)
+    def _last():
+        _fma(coef_ref, diag_ref, xi_ref, xp_ref, y_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "bn", "interpret"))
-def segment_round_masked_pallas(
-    nbr: jax.Array,
-    wgt: jax.Array,
-    slot: jax.Array,
-    diag: jax.Array,
-    bits: jax.Array,
-    x: jax.Array,
-    xp: jax.Array,
-    coef: jax.Array,
-    *,
-    bm: int = 128,
-    bd: int = 8,
-    bf: int = 128,
-    bn: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused masked sparse round, operands pre-padded.
-
-    ``bits`` is this round's (1, E) 0/1 edge-activity row (E = the padded
-    undirected edge count ``slot`` indexes into).
-    """
-    n, dmax = nbr.shape
-    n2, f = x.shape
-    if n != n2 or x.shape != xp.shape or wgt.shape != nbr.shape \
-            or slot.shape != nbr.shape or diag.shape != (n, 1) \
-            or bits.ndim != 2 or bits.shape[0] != 1:
-        raise ValueError(f"shape mismatch: nbr {nbr.shape}, wgt {wgt.shape}, "
-                         f"slot {slot.shape}, diag {diag.shape}, "
-                         f"bits {bits.shape}, X {x.shape}, Xp {xp.shape}")
-    bn = n if bn is None else bn
-    _check_tiles(n, dmax, f, bm, bd, bf, bn)
-    ns, nd = n // bn, dmax // bd
-    e = bits.shape[1]
-    grid = (n // bm, f // bf, ns, nd)
-    return pl.pallas_call(
-        functools.partial(segment_round_masked_kernel, ns, nd, bn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda i, j, s, d: (0, 0)),
-            pl.BlockSpec((1, e), lambda i, j, s, d: (0, 0)),
-            pl.BlockSpec((bm, bd), lambda i, j, s, d: (i, d)),
-            pl.BlockSpec((bm, bd), lambda i, j, s, d: (i, d)),
-            pl.BlockSpec((bm, bd), lambda i, j, s, d: (i, d)),
-            pl.BlockSpec((bm, 1), lambda i, j, s, d: (i, 0)),
-            pl.BlockSpec((bn, bf), lambda i, j, s, d: (s, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-            pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j, s, d: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, f), jnp.float32),
-        interpret=interpret,
-    )(coef, bits, nbr, wgt, slot, diag, x, x, xp)
-
-
-def segment_round_masked_batched_kernel(ns: int, nd: int, bn: int, coef_ref,
-                                        bits_ref, nbr_ref, wgt_ref, slot_ref,
-                                        diag_ref, xf_ref, xi_ref, xp_ref,
-                                        y_ref):
-    """Batched-grid masked body: blocks carry a leading length-1 graph dim."""
-    s = pl.program_id(3)
-    d = pl.program_id(4)
-
-    @pl.when((s == 0) & (d == 0))
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    w = wgt_ref[0]
-    live = jnp.take(bits_ref[0], slot_ref[0].reshape(-1)).reshape(w.shape)
-    wt = w * live
-    nbr = nbr_ref[0]
-    sel, local = _block_select(nbr, s, bn)
-    gathered = _gather_rows(xf_ref[0], local)
-    contrib = jnp.sum((wt * sel)[..., None] * gathered, axis=1)
-
-    @pl.when(s == 0)
-    def _with_drop():
-        drop = jnp.sum(w - wt, axis=1, keepdims=True)
-        y_ref[0] += contrib + drop * xi_ref[0]
-
-    @pl.when(s > 0)
-    def _partial():
-        y_ref[0] += contrib
-
-    @pl.when((s == ns - 1) & (d == nd - 1))
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        xi = xi_ref[...]
-        y_ref[...] = a * (y_ref[...] + diag_ref[...] * xi) + b * xi + c * xp_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "interpret"))
 def segment_round_masked_batched_pallas(
     nbrs: jax.Array,
     wgts: jax.Array,
@@ -394,46 +221,29 @@ def segment_round_masked_batched_pallas(
     bm: int = 128,
     bd: int = 8,
     bf: int = 128,
-    bn: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Masked fused sparse round over a stacked ensemble (dynamic sparse sweep).
 
-    nbrs/wgts/slots (G, N, D), diags (G, N, 1), bits (G, E) this round's
-    activity rows, Xs/Xps (G, N, F), coefs (G, 3) -> (G, N, F) fp32.
+    nbrs/wgts/slots (G, D, N), diags (G, 1, N), bits (G, E) this round's
+    activity rows, Xs/Xps (G, N, F), coefs (G, 3) -> (G, N, F).
     """
-    g, n, dmax = nbrs.shape
-    g2, n2, f = xs.shape
-    if g != g2 or n != n2 or xs.shape != xps.shape or coefs.shape != (g, 3) \
-            or wgts.shape != nbrs.shape or slots.shape != nbrs.shape \
-            or diags.shape != (g, n, 1) or bits.shape[0] != g:
-        raise ValueError(
-            f"shape mismatch: nbrs {nbrs.shape}, wgts {wgts.shape}, "
-            f"slots {slots.shape}, diags {diags.shape}, bits {bits.shape}, "
-            f"Xs {xs.shape}, coefs {coefs.shape}")
-    bn = n if bn is None else bn
-    _check_tiles(n, dmax, f, bm, bd, bf, bn)
-    ns, nd = n // bn, dmax // bd
-    e = bits.shape[1]
-    grid = (g, n // bm, f // bf, ns, nd)
+    g, n, f = xs.shape
+    dmax = nbrs.shape[1]
+    _check(g, n, dmax, f, bm, bd, bf, coefs, diags, (nbrs, wgts, slots),
+           (xs, xps))
+    if bits.ndim != 2 or bits.shape[0] != g:
+        raise ValueError(f"bits {bits.shape} is not one (G, E) row per cell")
+    in_specs, out_spec = _specs(bm, bd, bf, 2)
     return pl.pallas_call(
-        functools.partial(segment_round_masked_batched_kernel, ns, nd, bn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, s, d: (gg, 0)),
-            pl.BlockSpec((1, e), lambda gg, i, j, s, d: (gg, 0)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, 1), lambda gg, i, j, s, d: (gg, i, 0)),
-            pl.BlockSpec((1, bn, bf), lambda gg, i, j, s, d: (gg, s, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
+        functools.partial(segment_round_masked_batched_kernel, dmax // bd),
+        grid=(g, n // bm, f // bf, dmax // bd),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, bits, nbrs, wgts, slots, diags, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), wgts, gather_bits(bits, slots),
+      gather_rows(xs, nbrs), diags, xs, xps)
 
 
 # ---------------------------------------------------------------------------
@@ -446,54 +256,36 @@ def segment_round_masked_batched_pallas(
 # mass. Per output row i the returned mass is the column sum
 #
 #     drop[i] = sum_k W[k, i] * (1 - M[k, i])
-#             = sum_d wrev[i, d] * (1 - bits[slot[i, d]])
+#             = sum_d wrev[d, i] * (1 - bits[slot[d, i]])
 #
 # because masks are per undirected edge (bits hit both directions) and
-# wrev[i, d] = W[nbr[i,d], i] stores the reverse weight of slot d's edge.
+# wrev[d, i] = W[nbr[d,i], i] stores the reverse weight of slot d's edge.
 # Everything else matches the receiver-masked kernel.
 # ---------------------------------------------------------------------------
 
 
-def segment_round_sender_masked_batched_kernel(ns: int, nd: int, bn: int,
-                                               coef_ref, bits_ref, nbr_ref,
-                                               wgt_ref, wrev_ref, slot_ref,
-                                               diag_ref, xf_ref, xi_ref,
-                                               xp_ref, y_ref):
-    """Masked gather partial with sender-side (column) dropped-mass return."""
-    s = pl.program_id(3)
-    d = pl.program_id(4)
+def segment_round_sender_masked_batched_kernel(nd: int, coef_ref, wgt_ref,
+                                               wrev_ref, live_ref, xg_ref,
+                                               diag_ref, xi_ref, xp_ref,
+                                               y_ref):
+    """Masked weighted partial with sender-side (column) dropped-mass return."""
+    d = pl.program_id(3)
 
-    @pl.when((s == 0) & (d == 0))
+    @pl.when(d == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    w = wgt_ref[0]
-    live = jnp.take(bits_ref[0], slot_ref[0].reshape(-1)).reshape(w.shape)
-    wt = w * live
-    nbr = nbr_ref[0]
-    sel, local = _block_select(nbr, s, bn)
-    gathered = _gather_rows(xf_ref[0], local)
-    contrib = jnp.sum((wt * sel)[..., None] * gathered, axis=1)
+    live = _rows(live_ref[0])
+    y_ref[0] += (jnp.sum(_rows(wgt_ref[0]) * live * xg_ref[0], axis=0)
+                 + jnp.sum(_rows(wrev_ref[0]) * (1.0 - live), axis=0)
+                 * xi_ref[0])
 
-    @pl.when(s == 0)
-    def _with_drop():
-        drop = jnp.sum(wrev_ref[0] * (1.0 - live), axis=1, keepdims=True)
-        y_ref[0] += contrib + drop * xi_ref[0]
-
-    @pl.when(s > 0)
-    def _partial():
-        y_ref[0] += contrib
-
-    @pl.when((s == ns - 1) & (d == nd - 1))
-    def _fma():
-        a = coef_ref[0, 0]
-        b = coef_ref[0, 1]
-        c = coef_ref[0, 2]
-        xi = xi_ref[...]
-        y_ref[...] = a * (y_ref[...] + diag_ref[...] * xi) + b * xi + c * xp_ref[...]
+    @pl.when(d == nd - 1)
+    def _last():
+        _fma(coef_ref, diag_ref, xi_ref, xp_ref, y_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bd", "bf", "interpret"))
 def segment_round_sender_masked_batched_pallas(
     nbrs: jax.Array,
     wgts: jax.Array,
@@ -508,47 +300,29 @@ def segment_round_sender_masked_batched_pallas(
     bm: int = 128,
     bd: int = 8,
     bf: int = 128,
-    bn: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Sender-renorm masked sparse round over a stacked ensemble.
 
     Operands match ``segment_round_masked_batched_pallas`` plus wrevs
-    (G, N, D): the reverse weight of each slot's edge, 0 on padding slots.
-    Dropped mass returns to the sender's diagonal, keeping W_eff exactly
-    column stochastic (push_sum / ratio_consensus dynamic sweeps).
+    (G, D, N): the reverse weight of each slot's edge, 0 on padding
+    slots. Dropped mass returns to the sender's diagonal, keeping W_eff
+    exactly column stochastic (push_sum / ratio_consensus dynamic sweeps).
     """
-    g, n, dmax = nbrs.shape
-    g2, n2, f = xs.shape
-    if g != g2 or n != n2 or xs.shape != xps.shape or coefs.shape != (g, 3) \
-            or wgts.shape != nbrs.shape or wrevs.shape != nbrs.shape \
-            or slots.shape != nbrs.shape or diags.shape != (g, n, 1) \
-            or bits.shape[0] != g:
-        raise ValueError(
-            f"shape mismatch: nbrs {nbrs.shape}, wgts {wgts.shape}, "
-            f"wrevs {wrevs.shape}, slots {slots.shape}, diags {diags.shape}, "
-            f"bits {bits.shape}, Xs {xs.shape}, coefs {coefs.shape}")
-    bn = n if bn is None else bn
-    _check_tiles(n, dmax, f, bm, bd, bf, bn)
-    ns, nd = n // bn, dmax // bd
-    e = bits.shape[1]
-    grid = (g, n // bm, f // bf, ns, nd)
+    g, n, f = xs.shape
+    dmax = nbrs.shape[1]
+    _check(g, n, dmax, f, bm, bd, bf, coefs, diags,
+           (nbrs, wgts, wrevs, slots), (xs, xps))
+    if bits.ndim != 2 or bits.shape[0] != g:
+        raise ValueError(f"bits {bits.shape} is not one (G, E) row per cell")
+    in_specs, out_spec = _specs(bm, bd, bf, 3)
     return pl.pallas_call(
-        functools.partial(segment_round_sender_masked_batched_kernel, ns, nd, bn),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda gg, i, j, s, d: (gg, 0)),
-            pl.BlockSpec((1, e), lambda gg, i, j, s, d: (gg, 0)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, bd), lambda gg, i, j, s, d: (gg, i, d)),
-            pl.BlockSpec((1, bm, 1), lambda gg, i, j, s, d: (gg, i, 0)),
-            pl.BlockSpec((1, bn, bf), lambda gg, i, j, s, d: (gg, s, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-            pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bf), lambda gg, i, j, s, d: (gg, i, j)),
+        functools.partial(segment_round_sender_masked_batched_kernel,
+                          dmax // bd),
+        grid=(g, n // bm, f // bf, dmax // bd),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((g, n, f), jnp.float32),
         interpret=interpret,
-    )(coefs, bits, nbrs, wgts, wrevs, slots, diags, xs, xs, xps)
+    )(coefs.reshape(g, 1, 3), wgts, wrevs, gather_bits(bits, slots),
+      gather_rows(xs, nbrs), diags, xs, xps)

@@ -1,6 +1,7 @@
 """Launch layer: production meshes, multi-pod dry-run, train/serve drivers,
-roofline extraction. NOTE: import ``dryrun`` only as __main__ or in a fresh
-process — it forces 512 host devices and disables the Shardy partitioner."""
+roofline extraction, the entry points' compile cache. NOTE: import
+``dryrun`` only as __main__ or in a fresh process — it forces 512 CPU host
+devices and disables the Shardy partitioner."""
 from . import mesh, roofline
 from .mesh import make_cpu_mesh, make_production_mesh
 

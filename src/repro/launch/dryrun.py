@@ -1,5 +1,9 @@
 import os
 
+# A CPU rehearsal of the 512-chip programs: 512 forced host devices, and
+# never the attached accelerator (one chip cannot hold these meshes, and
+# a run that took it would keep every other process off it).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "").replace(
@@ -50,9 +54,13 @@ from ..dist import sharding as shd
 from ..models import build
 from .. import optim
 from .mesh import make_production_mesh
-from .roofline import HW, analyze_hlo, roofline_report
+from .roofline import analyze_hlo, peaks, roofline_report
 
 __all__ = ["input_specs", "dryrun_cell", "main"]
+
+# the production meshes are v5e pods (``launch/mesh.py``); the rehearsal
+# runs on host devices, so the roofline is read against the target chip
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 # params above this bf16-bytes-per-chip budget keep FSDP for serving
 SERVE_TP_HBM_BUDGET = 8e9
@@ -176,10 +184,9 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool,
             - mem["alias_bytes"]
         )
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax<=0.4.x returns [dict]
-            ca = ca[0] if ca else {}
         cost = analyze_hlo(compiled.as_text(), num_pods=2 if multi_pod else 1)
-        rep = roofline_report(cost, chips, _model_flops(cfg, shape))
+        rep = roofline_report(cost, chips, _model_flops(cfg, shape),
+                              peaks(PRODUCTION_DEVICE_KIND))
         rec.update(
             status="ok",
             lower_s=round(t_lower, 2),

@@ -18,9 +18,10 @@ HLO text itself:
     all-reduce 2S(n-1)/n, all-gather S_out(n-1)/n, reduce-scatter S_in(n-1)/n,
     all-to-all S(n-1)/n, collective-permute S.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI, ~6.25 GB/s/chip DCN (cross-pod). Collectives whose group size equals
-the pod count in a multi-pod lowering are tagged DCN.
+Hardware constants are keyed by ``device_kind`` (``PEAKS``; ``peaks(kind)``
+raises for a kind not listed, so no chip is ever read against another's
+rates). Collectives whose group size equals the pod count in a multi-pod
+lowering are tagged DCN.
 """
 from __future__ import annotations
 
@@ -28,14 +29,30 @@ import dataclasses
 import re
 from collections import defaultdict
 
-__all__ = ["HW", "HloCost", "analyze_hlo", "roofline_report"]
+__all__ = ["PEAKS", "HloCost", "analyze_hlo", "peaks", "roofline_report"]
 
-HW = {
-    "peak_flops": 197e12,   # bf16 per chip
-    "hbm_bw": 819e9,        # bytes/s per chip
-    "ici_bw": 50e9,         # bytes/s per link
-    "dcn_bw": 6.25e9,       # bytes/s per chip, cross-pod
+# Per-chip peaks by ``jax.Device.device_kind``. TPU v5e (reported as
+# "TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s chip-to-chip ICI (~50 GB/s per link over four
+# links); ~6.25 GB/s/chip DCN is the cross-pod planning figure.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,   # bf16 per chip
+        "hbm_bw": 819e9,        # bytes/s per chip
+        "ici_bw": 50e9,         # bytes/s per link
+        "dcn_bw": 6.25e9,       # bytes/s per chip, cross-pod
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak-rate table of one chip kind; an unlisted kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8, "c128": 16,
@@ -172,7 +189,7 @@ class HloCost:
     collective_counts: dict = dataclasses.field(default_factory=dict)
     dots: int = 0
 
-    def terms(self, hw: dict = HW) -> dict:
+    def terms(self, hw: dict) -> dict:
         t_c = self.flops / hw["peak_flops"]
         t_m = self.hbm_bytes / hw["hbm_bw"]
         t_net = self.wire_bytes_ici / hw["ici_bw"] + self.wire_bytes_dcn / hw["dcn_bw"]
@@ -325,7 +342,7 @@ def analyze_hlo(text: str, num_pods: int = 1) -> HloCost:
 
 
 def roofline_report(cost: HloCost, chips: int, model_flops_global: float | None,
-                    hw: dict = HW) -> dict:
+                    hw: dict) -> dict:
     terms = cost.terms(hw)
     rep = {
         "chips": chips,

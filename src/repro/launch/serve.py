@@ -13,6 +13,7 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config
 from ..models import build
 from ..serve import DecodeEngine, Request
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -23,6 +24,7 @@ def main(argv=None):
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--new-tokens", type=int, default=16)
     a = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(a.arch, smoke=True)
     model = build(cfg)

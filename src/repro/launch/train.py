@@ -24,6 +24,7 @@ from ..configs import ARCH_IDS, get_config
 from ..data import SyntheticStream
 from ..dist import SyncConfig, make_train_step
 from ..models import build
+from .compile_cache import enable_compile_cache
 from .mesh import make_cpu_mesh, make_production_mesh
 
 __all__ = ["main", "train_loop"]
@@ -113,6 +114,7 @@ def main(argv=None):
     ap.add_argument("--resume", default="none", choices=["none", "auto"])
     ap.add_argument("--production-mesh", action="store_true")
     a = ap.parse_args(argv)
+    enable_compile_cache()
     losses, _ = train_loop(
         a.arch, smoke=a.smoke, steps=a.steps, global_batch=a.batch,
         seq_len=a.seq, sync_mode=a.sync, pods=a.pods, lr=a.lr,

@@ -4,11 +4,12 @@ Axes and their mapping:
 
 * ``G`` (grid axis)  — every sweep cell: (topology family x size x graph
   draw) x theta design x alpha. Stacked as the leading dim of the (G, N, N)
-  weight batch and sharded across devices over the mesh 'data' axis
-  (``NamedSharding(mesh, P('data'))``, mesh from ``repro.launch.mesh``).
-* ``N`` (node axis)  — padded to the largest network in the grid; replicated.
-* ``F`` (trial axis) — initial-condition columns, sharded over the mesh
-  'model' axis (degenerate on single-host CPU, real on a pod).
+  weight batch and split across devices over the mesh 'data' axis (mesh
+  from ``repro.launch.mesh``): the whole scan runs inside ``shard_map``,
+  each device stepping its own block of cells (``_shard_rows``).
+* ``N`` (node axis)  — padded to the largest network in the grid; whole on
+  every device.
+* ``F`` (trial axis) — initial-condition columns, whole on every device.
 * ``T`` (iterations) — a single ``lax.scan``; the carry is (x, x_prev) only,
   so memory is O(G N F) while the returned MSE trajectory is O(T G F).
 
@@ -46,7 +47,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_cpu_mesh
 
@@ -97,22 +98,22 @@ def _dense_round_prim(wsp, renorm: str):
     mass-conserving push-sum family).
     """
     axis = 2 if renorm == "receiver" else 1
+    # HIGHEST: f32 on the TPU's MXU. Its default single bf16 pass rounds W,
+    # which then is no longer doubly stochastic and the mean drifts.
+    matmul = functools.partial(
+        jnp.einsum, "gij,gjf->gif", preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
     def prim(x, xp, coef, m=None):
         a = coef[:, 0, None, None]
         b = coef[:, 1, None, None]
         c = coef[:, 2, None, None]
         if m is None:
-            xw = jnp.einsum(
-                "gij,gjf->gif", wsp, x,
-                preferred_element_type=jnp.float32)
+            xw = matmul(wsp, x)
         else:
             wm = wsp * m
             drop = jnp.sum(wsp - wm, axis=axis)                   # (Gp, N)
-            xw = jnp.einsum(
-                "gij,gjf->gif", wm, x,
-                preferred_element_type=jnp.float32
-            ) + drop[:, :, None] * x
+            xw = matmul(wm, x) + drop[:, :, None] * x
         return a * xw + b * x + c * xp
     return prim
 
@@ -197,8 +198,8 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
 
     ``sparse`` (static) switches ``ws`` to the edge-space operand pytree:
     ``(src, dst, wdir, eid, diag)`` directed arrays on the jax backend, or
-    the pre-padded ``(nbrs, wgts, wrevs, slots, diags)`` ELL stacks on
-    pallas. The
+    the pre-padded slot-major ``(nbrs, wgts, wrevs, slots, diags)`` ELL
+    stacks on pallas. The
     dynamic path then feeds each round's raw (Gp, E) bits rows straight to
     the primitive — the dense (G, N, N) mask expansion never happens, which
     is what makes N = 1e5–1e6 dynamic-topology sweeps fit in memory.
@@ -246,24 +247,22 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
     xbar = x0.sum(axis=1, keepdims=True) * inv_n[:, None, None]   # (G, 1, F)
 
     if sparse and use_kernels:
-        # Sparse pallas: pre-padded ELL slices drive the batched segment-
-        # reduce kernel; `m` is this round's (Gp, E) bits rows gathered by
-        # undirected edge id inside the kernel — no (N, N) mask anywhere.
+        # Sparse pallas: pre-padded slot-major ELL slices drive the batched
+        # segment-reduce kernel; `m` is this round's (Gp, E) bits rows,
+        # gathered per slot by undirected edge id — no (N, N) mask anywhere.
         # ``renorm`` routes straight into the kernel layer: receiver-renorm
         # partitions run the row-masked kernel, sender-renorm partitions
-        # (push-sum family) the column-masked kernel via the wrev array —
-        # no jnp fallback on this path anymore. ``tiles`` carries the bn
-        # source-block size (VMEM policy, see kernels.ops.segment_bn).
+        # (push-sum family) the column-masked kernel via the wrev array.
         from repro.kernels.ops import batched_segment_round_prim, use_interpret
 
         nbrs, wgts, wrevs, slots, diags = ws
-        bm, bd, bf, bn = tiles
+        bm, bd, bf = tiles
         interpret = use_interpret()
 
         def make_prim(s, e, renorm):
             return batched_segment_round_prim(
                 nbrs[s:e], wgts[s:e], slots[s:e], diags[s:e],
-                wrevs=wrevs[s:e], bm=bm, bd=bd, bf=bf, bn=bn,
+                wrevs=wrevs[s:e], bm=bm, bd=bd, bf=bf,
                 interpret=interpret, renorm=renorm)
     elif sparse:
         nn = x0.shape[1]
@@ -389,23 +388,21 @@ def _prep_pallas_dense(ws, x0):
     return ws, x0, tiles, n + n_pad, f + f_pad
 
 
-def _prep_pallas_sparse(x0, edges, edge_w, diag_w, edge_counts, edge_w_rev,
-                        bits):
-    """Build the padded ELL pack for the sparse-pallas layout, host-side.
+def _prep_pallas_sparse(x0, edges, edge_w, diag_w, edge_counts, edge_w_rev):
+    """Build the padded slot-major ELL pack for the sparse-pallas layout.
 
-    Per-cell ELL arrays are built ONCE (N already padded to the row tile so
-    ``build_ell`` sizes them directly), the neighbor-slot axis is padded to
-    the common tile-rounded max degree, and the bits E axis to the kernel's
-    128-lane block. Padded slots have weight 0; padded bits columns are
-    never gathered. Returns ``(x0, wpack, tiles, bits, n, f)``.
+    Per-cell ELL arrays are built ONCE, host-side (N already padded to the
+    row tile so ``build_ell`` sizes them directly), the neighbor-slot axis
+    is padded to the common tile-rounded max degree (the slot tile shrinks
+    to that degree when it is smaller), and the stack is laid out for the
+    kernels (``ops.ell_slot_major``). Padded slots have weight 0. Returns
+    ``(x0, wpack, tiles, n, f)``.
     """
     from repro.kernels import ops as kops
 
     g, n, f = x0.shape
     bm, bd, bf = kops.segment_tiles(n, f, g, tune=True)
-    bn, n_tot = kops.segment_bn(n, bm, bf)
-    tiles = (bm, bd, bf, bn)
-    n_pad = n_tot - n
+    n_pad = kops._round_up(n, bm) - n
     f_pad = kops._round_up(f, bf) - f
     if n_pad or f_pad:
         x0 = np.pad(x0, ((0, 0), (0, n_pad), (0, f_pad)))
@@ -420,25 +417,17 @@ def _prep_pallas_sparse(x0, edges, edge_w, diag_w, edge_counts, edge_w_rev,
             else edge_w_rev[i, :int(ec[i])])
         for i in range(g)
     ]
-    d_max = kops._round_up(max(e_[0].shape[1] for e_ in ells), bd)
+    d_raw = max(e_[0].shape[1] for e_ in ells)
+    bd = min(bd, d_raw)
+    d_max = kops._round_up(d_raw, bd)
 
-    def padd(a):
-        return np.pad(a, ((0, 0), (0, d_max - a.shape[1])))
+    def stack(k):
+        return np.stack([np.pad(e_[k], ((0, 0), (0, d_max - e_[k].shape[1])))
+                         for e_ in ells])
 
-    wpack = (
-        np.stack([padd(e_[0]) for e_ in ells]),   # nbr  (G, N, D)
-        np.stack([padd(e_[1]) for e_ in ells]),   # wgt  (G, N, D)
-        np.stack([padd(e_[2]) for e_ in ells]),   # wrev (G, N, D)
-        np.stack([padd(e_[3]) for e_ in ells]),   # slot (G, N, D)
-        np.stack([e_[4] for e_ in ells]),         # diag (G, N, 1)
-    )
-    if bits is not None:
-        e_b = bits.shape[2]
-        bits = np.pad(
-            bits,
-            ((0, 0), (0, 0),
-             (0, kops._round_up(max(e_b, 1), 128) - e_b)))
-    return x0, wpack, tiles, bits, n, f
+    wpack = kops.ell_slot_major(stack(0), stack(1), stack(2), stack(3),
+                                np.stack([e_[4] for e_ in ells]))
+    return x0, wpack, (bm, bd, bf), n, f
 
 
 def _prep_jax_sparse(edges, edge_w, diag_w, edge_w_rev):
@@ -509,11 +498,10 @@ def run_batch(
       num_iters: rounds T.
       backend: 'jax' (einsum round) or 'pallas' (fused batched kernel).
       mesh: optional jax Mesh; defaults to the host mesh when more than one
-        device is visible. The G axis is sharded over 'data' (padded with
-        replicas of the last cell to divisibility; pad rows are dropped on
-        return). Mixed-algorithm grids slice G per partition inside the
-        program — align partition boundaries with the shard grid to avoid
-        resharding (single-algorithm grids always are).
+        device is visible. The G axis is split over 'data': each algorithm
+        partition is padded to a multiple of the axis size with replicas of
+        its last cell (dropped on return) and dealt out evenly, and every
+        device runs the scan on its own block.
       round_masks: optional ``RoundMasks`` (compressed per-round edge-activity
         bits, see ``repro.sweep.grid.build_round_masks``): routes through the
         dynamic-topology scan, where each round runs on the mass-preservingly
@@ -645,8 +633,8 @@ def run_batch(
     tiles = None
     wpack = None
     if backend == "pallas" and sparse:
-        x0, wpack, tiles, bits, n, f = _prep_pallas_sparse(
-            x0, edges, edge_w, diag_w, edge_counts, edge_w_rev, bits)
+        x0, wpack, tiles, n, f = _prep_pallas_sparse(
+            x0, edges, edge_w, diag_w, edge_counts, edge_w_rev)
     elif backend == "pallas":
         # pad N/F to the kernel's tile multiples ONCE, outside the scan; the
         # node mask (below) keeps padded rows out of the MSE, padded trial
@@ -666,99 +654,117 @@ def run_batch(
     # only auto-engage the mesh for real grids.
     if mesh is None and g > 1 and jax.device_count() > 1:
         mesh = make_cpu_mesh()
-    # backend="pallas" under a mesh needs no special casing: the batched
-    # round prims are wrapped in custom_partitioning over the G axis
-    # (kernels.ops), so GSPMD shards the kernel calls along "data" exactly
-    # like the jax einsum path.
 
-    g_pad = 0
     w_arrays = wpack if sparse else (ws,)
     nw = len(w_arrays)
     arrays = (*w_arrays, x0, mask, inv_n, coefs)
+    # which cell each row of the run's G axis holds, overall and per
+    # partition (a partition's carry comes back in its own row order)
+    rows = np.arange(g)
+    part_rows = [np.arange(s_, e_) for _, s_, e_ in algos]
     if mesh is not None:
-        ndata = mesh.shape["data"]
-        g_pad = (-g) % ndata
-        if g_pad:
-            # replicate the LAST cell so the pad extends the last algorithm
-            # partition (pad rows are dropped on return either way); every
-            # weight operand (dense ws, sparse directed/ELL stacks alike)
-            # is G-leading so one rule covers both layouts
-            arrays = tuple(
-                np.concatenate([a, np.repeat(a[-1:], g_pad, axis=0)], axis=0)
-                for a in arrays
-            )
-            if bits is not None:
-                bits = np.concatenate(
-                    [bits, np.repeat(bits[:, -1:], g_pad, axis=1)], axis=1
-                )
-                eidx = np.concatenate(
-                    [eidx, np.repeat(eidx[-1:], g_pad, axis=0)], axis=0
-                )
-            name, s, _ = algos[-1]
-            algos = algos[:-1] + ((name, s, g + g_pad),)
-        specs = tuple([P("data")] * nw) + (  # weight operands
-            P("data", None, "model"),     # x0
-            P("data"),                    # mask
-            P("data"),                    # inv_n
-            P("data"),                    # coefs
-        )
-        arrays = tuple(
-            jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip(arrays, specs)
-        )
+        rows, part_rows, algos = _shard_rows(algos, mesh.shape["data"])
+        arrays = tuple(a[rows] for a in arrays)
         if bits is not None:
-            bits = jax.device_put(bits, NamedSharding(mesh, P(None, "data")))
-            eidx = jax.device_put(eidx, NamedSharding(mesh, P("data")))
+            bits, eidx = bits[:, rows], eidx[rows]
 
     from repro.core.algorithms import registry_generation
 
-    ws_in = tuple(arrays[:nw]) if sparse else arrays[0]
+    statics = dict(
+        num_iters=num_iters, use_kernels=(backend == "pallas"), tiles=tiles,
+        layout=tuple(algos), algo_gen=registry_generation(), sparse=sparse)
     if debug_checks:
-        # checkify must functionalize the user checks BEFORE jit: wrap the
-        # raw scan (statics closed over) and throw on the first violated
-        # guard. This bypasses _sweep_scan's jit cache on purpose — the
-        # debug program is a different computation (error-state carrying).
-        from jax.experimental import checkify
-
         from repro.analysis.coefficient import traced_coef_sites
 
-        fn = functools.partial(
-            _sweep_scan.__wrapped__, num_iters=num_iters,
-            use_kernels=(backend == "pallas"), tiles=tiles, bits=bits,
-            eidx=eidx, layout=tuple(algos),
-            algo_gen=registry_generation(), sparse=sparse,
-            debug_checks=True,
-            dbg_sites=tuple(tuple(sorted(traced_coef_sites(name)))
-                            for name, _, _ in algos))
-        err, (x_fin, mse, carry_fin) = jax.jit(
-            checkify.checkify(fn, errors=checkify.user_checks)
-        )(ws_in, *arrays[nw:])
-        err.throw()
-    else:
+        statics.update(debug_checks=True, dbg_sites=tuple(
+            tuple(sorted(traced_coef_sites(name))) for name, _, _ in algos))
+    ws_in = tuple(arrays[:nw]) if sparse else arrays[0]
+    if mesh is None and not debug_checks:
         x_fin, mse, carry_fin = _sweep_scan(
-            ws_in, *arrays[nw:], num_iters=num_iters,
-            use_kernels=(backend == "pallas"),
-            tiles=tiles, bits=bits, eidx=eidx, layout=tuple(algos),
-            algo_gen=registry_generation(), sparse=sparse,
-        )
+            ws_in, *arrays[nw:], bits=bits, eidx=eidx, **statics)
+    else:
+        x_fin, mse, carry_fin = _scan_program(
+            mesh, bits is not None, tuple(sorted(statics.items())))(
+                ws_in, *arrays[nw:], bits, eidx)
     x_fin, mse = np.asarray(x_fin), np.asarray(mse)
-    if g_pad:
-        x_fin, mse = x_fin[:g], mse[:g]
-    if n != n_orig or f != f_orig:
-        x_fin, mse = x_fin[:, :n_orig, :f_orig], mse[:, :, :f_orig]
+    # the first row of each cell carries it (pad rows repeat a cell)
+    first = np.unique(rows, return_index=True)[1]
+    x_fin = x_fin[first, :n_orig, :f_orig]
+    mse = mse[first, :, :f_orig]
     if not return_taps:
         return x_fin, mse
-    # G-padding only ever extends the LAST partition, so slicing each
-    # partition's taps to its pre-padding span drops exactly the pad rows.
     # Aux carry slots (everything past num_taps) are algorithm-internal
     # estimator state, not network state: excluded by contract.
-    taps = tuple(
-        (name, s_p, e_p, tuple(
-            np.asarray(t)[:e_p - s_p, :n_orig, :f_orig]
-            for t in sub[:get_algorithm(name).num_taps]))
-        for (name, s_p, e_p), sub in zip(parts_out, carry_fin)
-    )
-    return x_fin, mse, taps
+    taps = []
+    for (name, s_p, e_p), prow, sub in zip(parts_out, part_rows, carry_fin):
+        keep = np.unique(prow, return_index=True)[1]
+        taps.append((name, s_p, e_p, tuple(
+            np.asarray(t)[keep, :n_orig, :f_orig]
+            for t in sub[:get_algorithm(name).num_taps])))
+    return x_fin, mse, tuple(taps)
+
+
+def _shard_rows(algos, ndata: int):
+    """Lay the G axis out for ``ndata`` devices running the scan locally.
+
+    Each algorithm partition is padded to a multiple of ``ndata`` (repeating
+    its last cell) and dealt out in equal contiguous chunks, so every
+    device's block holds the same partition layout — one local program, no
+    cross-device traffic inside the scan. Returns ``(rows, part_rows,
+    local_layout)``:
+    ``rows[k]`` is the cell at row k of the sharded G axis (device d owns
+    rows [d*B, (d+1)*B)), ``part_rows[p]`` the cells of partition p in the
+    order its carry comes back, ``local_layout`` the per-device partitions.
+    """
+    chunks, local, start = [], [], 0
+    for name, s, e in algos:
+        idx = np.arange(s, e)
+        idx = np.concatenate([idx, np.full((-len(idx)) % ndata, e - 1)])
+        chunks.append(idx.reshape(ndata, -1))
+        local.append((name, start, start + chunks[-1].shape[1]))
+        start += chunks[-1].shape[1]
+    rows = np.concatenate(chunks, axis=1).reshape(-1)
+    return rows, [c.reshape(-1) for c in chunks], tuple(local)
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_program(mesh, dynamic: bool, statics: tuple):
+    """The jitted scan for a mesh run and/or the checkify twin.
+
+    Under a mesh the whole scan runs inside ``shard_map`` over the 'data'
+    axis: each device steps its own block of G cells (``_shard_rows``), so
+    the round kernels only ever see local operands — nothing for the
+    partitioner to split, and nothing crosses devices. ``debug_checks``
+    functionalizes the user checks with checkify (the debug program is a
+    different computation, error state carried). Cached per (mesh, statics)
+    so repeat runs reuse the compiled program.
+    """
+    kw = dict(statics)
+    debug = kw.get("debug_checks", False)
+
+    def fn(ws, x0, mask, inv_n, coefs, bits, eidx):
+        return _sweep_scan.__wrapped__(ws, x0, mask, inv_n, coefs, bits=bits,
+                                       eidx=eidx, **kw)
+
+    if mesh is not None:
+        data = P("data")
+        fn = jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=(data,) * 5 + ((P(None, "data"), data) if dynamic
+                                   else (P(), P())),
+            out_specs=data, check_vma=False)
+    if debug:
+        from jax.experimental import checkify
+
+        checked = jax.jit(checkify.checkify(fn, errors=checkify.user_checks))
+
+        def run(*args):
+            err, out = checked(*args)
+            err.throw()
+            return out
+
+        return run
+    return jax.jit(fn)
 
 
 @dataclasses.dataclass(frozen=True)

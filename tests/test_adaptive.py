@@ -41,14 +41,14 @@ def _chain_interval(n):
 # ---------------------------------------------------------------------------
 
 def test_alpha_star_jnp_matches_host_to_f64_roundoff():
-    from jax.experimental import enable_x64
+    import jax
     import jax.numpy as jnp
 
     lams = np.linspace(0.0, 0.999999, 251)
     thetas = [accel.theta_ls()] + [
         accel.theta_asymptotic(e) for e in (0.05, 0.5, 2.0)
     ]
-    with enable_x64():
+    with jax.enable_x64(True):
         for th in thetas:
             host = np.array([accel.alpha_star(lam, th) for lam in lams])
             twin = np.asarray(

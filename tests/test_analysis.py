@@ -125,9 +125,7 @@ def test_broken_fixture_trips_exactly_one_finding(spec, passname, rule):
     assert len(errors) == 1, render_text(findings)
     assert errors[0].rule == rule
     assert errors[0].passname == passname
-    # the mesh pass names the offending kernel (whole-grid trace), the
-    # per-registration passes name the algorithm spec
-    assert errors[0].obj == spec or passname == "mesh-kernel"
+    assert errors[0].obj == spec
 
 
 def test_fixture_selftest_roundtrip():
@@ -187,29 +185,6 @@ def test_verify_static_entry_point():
     finally:
         fixtures.unregister_fixtures()
     assert any(f.rule == "coef-mass" and f.severity == "error" for f in bad)
-
-
-# ---------------------------------------------------------------------------
-# Satellite: cp fired-counter reset/context API.
-# ---------------------------------------------------------------------------
-
-def test_cp_partition_counter_api():
-    from repro.kernels import ops
-
-    ops.reset_cp_partition_count()
-    assert ops.cp_partition_count() == 0
-    with ops.cp_partition_calls() as fired:
-        assert fired() == 0
-        ops._CP_PARTITION_CALLS += 3  # what the partition rule does
-        assert fired() == 3
-        with ops.cp_partition_calls() as inner:  # scoped: no leakage
-            assert inner() == 0
-            ops._CP_PARTITION_CALLS += 2
-            assert inner() == 2
-        assert fired() == 5
-    assert ops.cp_partition_count() == 5
-    ops.reset_cp_partition_count()
-    assert ops.cp_partition_count() == 0
 
 
 # ---------------------------------------------------------------------------

@@ -147,16 +147,17 @@ def test_segment_outputs_bit_identical_across_candidate_tiles():
             gph.edges, e_w, np.pad(d_w, (0, n_pad)), nn + n_pad)
         d_pad = ops._round_up(nbr.shape[1], bd) - nbr.shape[1]
         nbr, wgt = (np.pad(a, ((0, 0), (0, d_pad))) for a in (nbr, wgt))
+        nbrs, wgts, diags = ops.ell_slot_major(
+            nbr[None], wgt[None].astype(np.float32),
+            diag[None].astype(np.float32))
         f_pad = ops._round_up(ff, bf) - ff
-        from repro.kernels.segment_round import segment_round_pallas
-        y = segment_round_pallas(
-            jnp.asarray(nbr), jnp.asarray(wgt, jnp.float32),
-            jnp.asarray(diag, jnp.float32),
-            jnp.pad(x, ((0, n_pad), (0, f_pad))),
-            jnp.pad(xp, ((0, n_pad), (0, f_pad))),
+        y = ops.segment_round_batched_pallas(
+            jnp.asarray(nbrs), jnp.asarray(wgts), jnp.asarray(diags),
+            jnp.pad(x, ((0, n_pad), (0, f_pad)))[None],
+            jnp.pad(xp, ((0, n_pad), (0, f_pad)))[None],
             jnp.asarray([[1.1, 0.2, -0.3]], jnp.float32),
             bm=bm, bd=bd, bf=bf, interpret=interp)
-        outs.append(np.asarray(y)[:nn, :ff])
+        outs.append(np.asarray(y)[0, :nn, :ff])
     for o in outs[1:]:
         np.testing.assert_array_equal(o, outs[0])
 

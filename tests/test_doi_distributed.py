@@ -92,19 +92,20 @@ def test_fabric_matvec_matches_dense_application(rng):
 def test_inmesh_doi_tracks_dense_f64():
     out = _run("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric, distributed_lambda2
         # K per graph: (lambda3/lambda2)^K must undercut the tolerance
         # (chain's gap ratio ~0.73 needs K ~ N^2, Sec III-D)
         for p, kind, k in [(8, "ring", 40), (6, "chain", 160), (4, "chain", 40)]:
             fab = make_fabric(p, kind)
-            mesh = jax.make_mesh((p,), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((p,), ("pod",))
             def est(key):
                 return distributed_lambda2("pod", p, key, num_iters=k,
                                            fabric=fab, dtype=jnp.float64)[None]
             f = shard_map(est, mesh=mesh, in_specs=P(), out_specs=P("pod"),
-                          check_rep=False)
+                          check_vma=False)
             lam = jax.jit(f)(jax.random.PRNGKey(0))
             err = abs(float(lam[0]) - fab.lambda2)
             assert err < 1e-6, (p, kind, float(lam[0]), fab.lambda2)
@@ -121,17 +122,18 @@ def test_inmesh_doi_f32_moderate_k():
     re-injection stays below tolerance, single precision still tracks."""
     out = _run("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric, distributed_lambda2
         fab = make_fabric(8, "ring")
-        mesh = jax.make_mesh((8,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("pod",))
         def est(key):
             return distributed_lambda2("pod", 8, key, num_iters=16,
                                        normalize_every=4, fabric=fab,
                                        dtype=jnp.float32)[None]
         f = shard_map(est, mesh=mesh, in_specs=P(), out_specs=P("pod"),
-                      check_rep=False)
+                      check_vma=False)
         lam = float(jax.jit(f)(jax.random.PRNGKey(0))[0])
         assert abs(lam - fab.lambda2) < 1e-3, (lam, fab.lambda2)
         print("OK inmesh f32", lam)
@@ -150,7 +152,7 @@ def test_inmesh_doi_bitwise_matches_host_p_le_8():
     """
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import doi, topology
         from repro.dist import make_fabric, distributed_lambda2, fabric_matvec
@@ -159,13 +161,14 @@ def test_inmesh_doi_bitwise_matches_host_p_le_8():
         for p, kind, g in graphs:
             fab = make_fabric(p, kind)
             v0 = np.random.default_rng(7).standard_normal(p)
-            mesh = jax.make_mesh((p,), ("pod",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((p,), ("pod",))
             def est(_):
                 return distributed_lambda2("pod", p, None, num_iters=40,
                                            fabric=fab, v_init=v0,
                                            dtype=jnp.float64)[None]
             f = shard_map(est, mesh=mesh, in_specs=P(), out_specs=P("pod"),
-                          check_rep=False)
+                          check_vma=False)
             lam_mesh = np.asarray(jax.jit(f)(jnp.zeros(())))
             hosts = {
                 c: doi.estimate_lambda2(

@@ -39,7 +39,8 @@ def test_consensus_p2_exactly_matches_allreduce():
         from repro.models import build
         from repro.dist import make_train_step, SyncConfig
         from repro import optim
-        mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 1), ("pod", "data", "model"))
         cfg = get_config("yi-9b", smoke=True)
         model = build(cfg); opt = optim.adamw(1e-3)
         batch = {"tokens": jnp.zeros((8, 16), jnp.int32),
@@ -85,11 +86,12 @@ def test_accel_gossip_reaches_eps_in_fewer_rounds_p4_ring():
     predict, and accelerated needs strictly fewer rounds."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric
         from repro.dist.gossip import accel_gossip, gossip
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "ring")
         eps = 1e-3
         r_acc, r_mem = fab.rounds_for(eps), fab.rounds_for_memoryless(eps)
@@ -102,7 +104,7 @@ def test_accel_gossip_reaches_eps_in_fewer_rounds_p4_ring():
             def body(b):
                 return run(b[0], "pod", fab, rounds)[None]
             f = shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                          check_rep=False)
+                          check_vma=False)
             y = jax.jit(f)(x)
             return float(jnp.linalg.norm(y - target[None])) / denom
 
@@ -135,11 +137,12 @@ def test_pairwise_gossip_p4_ring_matches_host_and_conserves_mean():
     the algorithm_gossip registry dispatcher routes to the same program."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric
         from repro.dist.gossip import algorithm_gossip, pairwise_gossip
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "ring")
         edges = [(i, j) for i in range(4) for j in range(i + 1, 4)
                  if fab.w[i, j] != 0.0]
@@ -152,7 +155,7 @@ def test_pairwise_gossip_p4_ring_matches_host_and_conserves_mean():
                 return fn(b[0], "pod", fab, rounds, schedule=sched[:rounds],
                           **kw)[None]
             return jax.jit(shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                     out_specs=P("pod"), check_rep=False))
+                                     out_specs=P("pod"), check_vma=False))
 
         y = runner(pairwise_gossip, 40)(x)
         # host reference: apply the Boyd matrix of each scheduled edge
@@ -184,11 +187,12 @@ def test_masked_gossip_degrades_gracefully_p4():
     the in-mesh run matches the host masked-W reference."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric, edge_permutations
         from repro.dist.gossip import accel_gossip, gossip
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "ring")
         perms = edge_permutations(fab.w)
         nm = len(perms)
@@ -201,7 +205,7 @@ def test_masked_gossip_degrades_gracefully_p4():
             def body(b):
                 return fn(b[0], "pod", fab, R, drop_mask=mask)[None]
             f = shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                          check_rep=False)
+                          check_vma=False)
             return jax.jit(f)(x)
 
         mask = jnp.asarray((rng.random((R, nm)) >= 0.4), jnp.float32)
@@ -216,7 +220,7 @@ def test_masked_gossip_degrades_gracefully_p4():
             def plain(b):
                 return fn(b[0], "pod", fab, R)[None]
             y0 = jax.jit(shard_map(plain, mesh=mesh, in_specs=P("pod"),
-                                   out_specs=P("pod"), check_rep=False))(x)
+                                   out_specs=P("pod"), check_vma=False))(x)
             d1 = float(jnp.abs(y1 - y0).max())
             assert d1 < 1e-6, (kind, d1)
         # all matchings down every round: W_eff = I, state frozen (up to the
@@ -253,11 +257,12 @@ def test_adaptive_accel_gossip_p4():
     registry dispatcher routes to the identical program."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric
         from repro.dist.gossip import accel_gossip, adaptive_accel_gossip, algorithm_gossip
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "chain")
         R = max(fab.rounds_for(1e-3), 8)
 
@@ -265,7 +270,7 @@ def test_adaptive_accel_gossip_p4():
             def body(b):
                 return fn(b[0], "pod", fab, R, **kw)[None]
             return jax.jit(shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                     out_specs=P("pod"), check_rep=False))
+                                     out_specs=P("pod"), check_vma=False))
 
         x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 64)), jnp.float32)
         y = runner(adaptive_accel_gossip, resolve_every=4, doi_iters=8)(x)
@@ -292,17 +297,18 @@ def test_adaptive_accel_gossip_p4():
 def test_inmesh_doi_matches_theory():
     out = _run("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric, distributed_lambda2
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "chain")
         def est(key):
             return distributed_lambda2("pod", 4, key, num_iters=40,
                                        topology_kind="chain",
                                        dtype=jnp.float64)[None]
         f = shard_map(est, mesh=mesh, in_specs=P(), out_specs=P("pod"),
-                      check_rep=False)
+                      check_vma=False)
         lam = float(jax.jit(f)(jax.random.PRNGKey(3))[0])
         assert abs(lam - fab.lambda2) < 1e-4, (lam, fab.lambda2)
         print("OK doi", lam)
@@ -315,7 +321,8 @@ def test_pipeline_matches_reference():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist.pipeline import pipeline_forward, reference_forward
-        mesh = jax.make_mesh((4,), ("stage",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("stage",))
         rng = np.random.default_rng(0)
         w1 = jnp.asarray(rng.standard_normal((4, 2, 16, 32)), jnp.float32) * 0.1
         w2 = jnp.asarray(rng.standard_normal((4, 2, 32, 16)), jnp.float32) * 0.1
@@ -333,12 +340,13 @@ def test_pipeline_matches_reference():
 def test_int8_wire_consensus_still_converges():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist import make_fabric
         from repro.dist.gossip import accel_gossip
         from repro.dist.compression import Int8Wire
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         fab = make_fabric(4, "ring")
         R = fab.rounds_for(1e-3)
         def body(x):
@@ -346,7 +354,7 @@ def test_int8_wire_consensus_still_converges():
             out = accel_gossip(x, "pod", fab, R, wire=Int8Wire())
             return out[None]
         f = shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
-                      check_rep=False)
+                      check_vma=False)
         x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 64)), jnp.float32)
         y = jax.jit(f)(x)
         target = x.mean(axis=0)
@@ -360,41 +368,51 @@ def test_int8_wire_consensus_still_converges():
 @pytest.mark.slow
 def test_pallas_sweep_partitions_over_g_on_4_devices():
     """The tentpole seam: backend="pallas" under a forced 4-device mesh runs
-    the batched round kernels through their custom_partitioning wrappers —
-    the G axis shards over "data" (the partition callback must actually
-    fire), dense and sparse layouts both match the jax backend to f32
-    tolerances, and a dynamic grid with a sender-renorm partition (push_sum)
-    exercises the masked + column-masked kernel variants under the mesh."""
+    the scan inside shard_map over the G axis — each device steps its own
+    block of cells, each algorithm partition padded to a multiple of 4
+    (3 accel + 3 push_sum cells per layout here, so both partitions pad) —
+    and dense and sparse layouts both match the jax backend to f32
+    tolerances and a one-device mesh run bit for bit, final carry taps
+    included; a dynamic grid with a sender-renorm partition (push_sum)
+    exercises the masked + column-masked kernel variants."""
     out = _run("""
         import numpy as np, jax
         assert jax.device_count() == 4, jax.device_count()
-        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh
         from repro.sweep import SweepSpec, build_ensemble, run_ensemble
         from repro.sweep.engine import build_round_masks
 
+        one = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
         for layout in ("dense", "sparse"):
             spec = SweepSpec(
-                topologies=("chain", "rgg"), sizes=(12, 20),
+                topologies=("chain", "rgg", "grid2d"), sizes=(16,),
                 designs=("asymptotic",), alphas=(1.0,), num_trials=3,
                 seed=7, algorithms=("accel", "push_sum"),
-                dynamics=("static", "bernoulli:0.2"), layout=layout,
+                dynamics=("bernoulli:0.2",), layout=layout,
             )
             ens = build_ensemble(spec)
+            assert ens.layout == (("accel", 0, 3), ("push_sum", 3, 6))
             masks = build_round_masks(ens, 30, seed=7)
-            with ops.cp_partition_calls() as fired_in_scope:
-                r_p = run_ensemble(ens, num_iters=30, backend="pallas",
-                                   round_masks=masks)
-                fired = fired_in_scope()
-            assert fired > 0, (layout, fired)  # GSPMD used our partition rule
+            r_p = run_ensemble(ens, num_iters=30, backend="pallas",
+                               round_masks=masks, return_taps=True)
             r_j = run_ensemble(ens, num_iters=30, backend="jax",
                                round_masks=masks)
             np.testing.assert_allclose(
                 r_p.x_final, r_j.x_final, rtol=2e-4, atol=1e-5)
             np.testing.assert_allclose(
                 r_p.mse, r_j.mse, rtol=2e-4, atol=1e-7)
-            print("OK cp", layout, fired)
+            r_1 = run_ensemble(ens, num_iters=30, backend="pallas",
+                               round_masks=masks, return_taps=True, mesh=one)
+            np.testing.assert_array_equal(r_p.x_final, r_1.x_final)
+            np.testing.assert_array_equal(r_p.mse, r_1.mse)
+            for (n4, s4, e4, t4), (n1, s1, e1, t1) in zip(r_p.taps, r_1.taps):
+                assert (n4, s4, e4) == (n1, s1, e1)
+                for a, b in zip(t4, t1):
+                    assert a.shape == (e4 - s4, 16, 3)
+                    np.testing.assert_array_equal(a, b)
+            print("OK sharded", layout)
     """)
-    assert "OK cp dense" in out and "OK cp sparse" in out
+    assert "OK sharded dense" in out and "OK sharded sparse" in out
 
 
 def test_sharding_rules_abstract_mesh():
@@ -402,7 +420,7 @@ def test_sharding_rules_abstract_mesh():
     out = _run("""
         from jax.sharding import AbstractMesh, PartitionSpec as P
         from repro.dist.sharding import partition_spec
-        mesh = AbstractMesh((("data", 16), ("model", 16)))
+        mesh = AbstractMesh((16, 16), ("data", "model"))
         # TP beats cache_seq for 'model' when kv_heads divide
         s = partition_spec((32, 32768, 32, 128), ("batch", "cache_seq", "kv_heads", "head_dim"), mesh)
         assert s == P("data", None, "model"), s
@@ -418,7 +436,7 @@ def test_sharding_rules_abstract_mesh():
         # non-divisible dims are replicated, not unevenly sharded
         s = partition_spec((56,), ("heads",), mesh)
         assert s == P(), s
-        multi = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+        multi = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         s = partition_spec((256, 4096), ("batch", None), multi)
         assert s == P(("pod", "data")), s
         print("OK rules")

@@ -286,18 +286,18 @@ def test_sparse_large_n_mean_conserved_and_converging():
 
 
 # ---------------------------------------------------------------------------
-# bn source-block tiling + sender-renorm ELL kernel
+# slot tiling + sender-renorm ELL kernel
 # ---------------------------------------------------------------------------
 
 
-def _ell_fixture(n, f, g=2, seed=5):
-    """Batched ELL operands (tile-padded) + matching dense W and bits."""
+def _ell_fixture(n, f, g=2, seed=5, bd=8):
+    """Batched slot-major ELL operands (tile-padded) + matching dense W, bits."""
     import jax.numpy as jnp
 
     from repro.kernels import ops
 
     rng = np.random.default_rng(seed)
-    bm, bd, bf = 128, 8, 128
+    bm = 128
     n_t = ops._round_up(n, bm)
     gph = topology.random_geometric_sparse(n, rng)
     edge_w, diag_w = weights.metropolis_hastings_edges(gph)
@@ -306,9 +306,11 @@ def _ell_fixture(n, f, g=2, seed=5):
     d_pad = ops._round_up(nbr.shape[1], bd) - nbr.shape[1]
     nbr, wgt, wrev, slot = (
         np.pad(a, ((0, 0), (0, d_pad))) for a in (nbr, wgt, wrev, slot))
+    nbr, wgt, wrev, slot, diag = ops.ell_slot_major(
+        nbr, wgt.astype(np.float32), wrev.astype(np.float32), slot,
+        diag.astype(np.float32))
     e = gph.num_edges
     bits = (rng.random((g, e)) < 0.6).astype(np.float32)
-    bits_p = np.pad(bits, ((0, 0), (0, ops._round_up(e, 128) - e)))
     w = np.zeros((n_t, n_t))
     w[gph.edges[:, 0], gph.edges[:, 1]] = edge_w
     w += w.T
@@ -319,61 +321,49 @@ def _ell_fixture(n, f, g=2, seed=5):
     coefs = np.stack([[1.1, 0.2, -0.3]] * g).astype(np.float32)
     return dict(
         gph=gph, w=w, n_t=n_t, bits=bits,
-        nbrs=stack(nbr), wgts=stack(wgt.astype(np.float32)),
-        wrevs=stack(wrev.astype(np.float32)), slots=stack(slot),
-        diags=stack(diag.astype(np.float32)),
-        bitsj=jnp.asarray(bits_p), xs=jnp.asarray(xs), xps=jnp.asarray(xps),
+        nbrs=stack(nbr), wgts=stack(wgt), wrevs=stack(wrev),
+        slots=stack(slot), diags=stack(diag),
+        bitsj=jnp.asarray(bits), xs=jnp.asarray(xs), xps=jnp.asarray(xps),
         coefs=jnp.asarray(coefs))
 
 
-def test_segment_round_bn_tiling_matches_full_n():
-    """bn < N (multi-block source axis) computes what bn = N computes, for
-    the plain, receiver-masked, and sender-masked batched kernels alike."""
+def _segment_call(variant, fx, bd, bf):
     from repro.kernels import ops, segment_round as sk
 
-    fx = _ell_fixture(300, 64)   # n_t = 384 -> 3 source blocks at bn=128
-    interp = ops.use_interpret()
-    kw = dict(bm=128, bd=8, bf=64, interpret=interp)
-
-    y_full = sk.segment_round_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["diags"], fx["xs"], fx["xps"],
-        fx["coefs"], bn=None, **kw)
-    y_tile = sk.segment_round_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["diags"], fx["xs"], fx["xps"],
-        fx["coefs"], bn=128, **kw)
-    np.testing.assert_allclose(
-        np.asarray(y_tile), np.asarray(y_full), rtol=1e-6, atol=1e-6)
-
-    y_full = sk.segment_round_masked_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["slots"], fx["diags"], fx["bitsj"],
-        fx["xs"], fx["xps"], fx["coefs"], bn=None, **kw)
-    y_tile = sk.segment_round_masked_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["slots"], fx["diags"], fx["bitsj"],
-        fx["xs"], fx["xps"], fx["coefs"], bn=128, **kw)
-    np.testing.assert_allclose(
-        np.asarray(y_tile), np.asarray(y_full), rtol=1e-6, atol=1e-6)
-
-    y_full = sk.segment_round_sender_masked_batched_pallas(
+    kw = dict(bm=128, bd=bd, bf=bf, interpret=ops.use_interpret())
+    if variant == "plain":
+        return sk.segment_round_batched_pallas(
+            fx["nbrs"], fx["wgts"], fx["diags"], fx["xs"], fx["xps"],
+            fx["coefs"], **kw)
+    if variant == "masked":
+        return sk.segment_round_masked_batched_pallas(
+            fx["nbrs"], fx["wgts"], fx["slots"], fx["diags"], fx["bitsj"],
+            fx["xs"], fx["xps"], fx["coefs"], **kw)
+    return sk.segment_round_sender_masked_batched_pallas(
         fx["nbrs"], fx["wgts"], fx["wrevs"], fx["slots"], fx["diags"],
-        fx["bitsj"], fx["xs"], fx["xps"], fx["coefs"], bn=None, **kw)
-    y_tile = sk.segment_round_sender_masked_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["wrevs"], fx["slots"], fx["diags"],
-        fx["bitsj"], fx["xs"], fx["xps"], fx["coefs"], bn=128, **kw)
-    np.testing.assert_allclose(
-        np.asarray(y_tile), np.asarray(y_full), rtol=1e-6, atol=1e-6)
+        fx["bitsj"], fx["xs"], fx["xps"], fx["coefs"], **kw)
+
+
+@pytest.mark.parametrize("variant", ["plain", "masked", "sender"])
+def test_segment_round_slot_tiles_agree(variant):
+    """Splitting the slot reduction over bd-wide grid steps (bd = 1, 2, 4
+    against the whole padded degree in one step) computes the same round,
+    for the plain, receiver-masked and sender-masked batched kernels."""
+    fx = _ell_fixture(200, 64)
+    d = fx["nbrs"].shape[1]
+    y_one = np.asarray(_segment_call(variant, fx, d, 64))
+    for bd in (1, 2, 4):
+        np.testing.assert_allclose(
+            np.asarray(_segment_call(variant, fx, bd, 64)), y_one,
+            rtol=1e-6, atol=1e-6, err_msg=f"bd={bd}")
 
 
 def test_sender_masked_segment_matches_dense_column_renorm():
     """Sparse sender-renorm kernel == dense column-renorm oracle: dropped
     mass W_ji of a dead edge returns to sender i's diagonal."""
-    from repro.kernels import ops, segment_round as sk
-
     fx = _ell_fixture(120, 32)
     gph, w, n_t = fx["gph"], fx["w"], fx["n_t"]
-    y = sk.segment_round_sender_masked_batched_pallas(
-        fx["nbrs"], fx["wgts"], fx["wrevs"], fx["slots"], fx["diags"],
-        fx["bitsj"], fx["xs"], fx["xps"], fx["coefs"],
-        bm=128, bd=8, bf=32, bn=None, interpret=ops.use_interpret())
+    y = _segment_call("sender", fx, 8, 32)
     for i in range(fx["bits"].shape[0]):
         m = np.eye(n_t)
         m[gph.edges[:, 0], gph.edges[:, 1]] = fx["bits"][i]
@@ -408,16 +398,3 @@ def test_build_ell_wrev_is_transposed_weight():
             else:
                 np.testing.assert_allclose(wgt[i, d], w[i, nbr[i, d]])
                 np.testing.assert_allclose(wrev[i, d], w[nbr[i, d], i])
-
-
-def test_segment_bn_policy_respects_vmem_budget(monkeypatch):
-    from repro.kernels import ops
-
-    # small N: one full-N block, no tiling
-    bn, n_t = ops.segment_bn(100, 128, 128)
-    assert (bn, n_t) == (128, 128)
-    # squeeze the budget: the (bn, bf) block must fit 64 KiB -> bn = 128
-    monkeypatch.setenv("REPRO_SEGMENT_VMEM_BUDGET", str(64 * 1024))
-    bn, n_t = ops.segment_bn(1000, 128, 128)
-    assert bn == 128 and n_t % bn == 0 and n_t >= 1000
-    assert bn * 128 * 4 <= 64 * 1024

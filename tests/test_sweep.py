@@ -177,3 +177,19 @@ def test_run_batch_rejects_unknown_backend(rng):
 def test_spec_rejects_unknown_design():
     with pytest.raises(ValueError, match="design"):
         SweepSpec(designs=("memoryless", "chebyshev"))
+
+
+def test_shard_rows_deal_every_partition_evenly():
+    """Under a mesh each algorithm partition is padded to a multiple of the
+    'data' axis with its last cell and dealt out in equal chunks, so every
+    device's block holds the same local partition layout."""
+    from repro.sweep.engine import _shard_rows
+
+    rows, part_rows, local = _shard_rows((("accel", 0, 5), ("push_sum", 5, 7)), 4)
+    assert local == (("accel", 0, 2), ("push_sum", 2, 3))
+    assert part_rows[0].tolist() == [0, 1, 2, 3, 4, 4, 4, 4]
+    assert part_rows[1].tolist() == [5, 6, 6, 6]
+    blocks = rows.reshape(4, 3)           # device d owns row block d
+    assert blocks.tolist() == [[0, 1, 5], [2, 3, 6], [4, 4, 6], [4, 4, 6]]
+    assert set(rows.tolist()) == set(range(7))
+
