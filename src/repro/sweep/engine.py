@@ -47,6 +47,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_cpu_mesh
@@ -60,15 +61,30 @@ from .grid import (
     build_round_masks,
 )
 
-__all__ = ["SweepResult", "run_batch", "run_ensemble", "run_sweep", "trace_count"]
+__all__ = ["SweepResult", "counters", "run_batch", "run_ensemble", "run_sweep",
+           "trace_count"]
 
-# Incremented at trace time inside the jitted engine body: one bump per
-# compilation. Tests assert a full heterogeneous grid costs exactly one.
-_TRACE_COUNT = 0
+# The engine's counters, integer adds on the host:
+#   traces          compilations of the engine body (bumped at trace time;
+#                   tests assert a full heterogeneous grid costs exactly one)
+#   batches         run_batch calls past validation (one per F chunk)
+#   bytes_in        bytes of every array put on the device, bits included
+#   bytes_out       bytes of the outputs fetched back to the host
+#   entries_real    round-matrix entries x trial columns of real nodes
+#   entries_padded  the same for what the round computes: dense G n_pad^2
+#                   F_pad, ELL G n_pad D F_pad, directed arrays G 2E F
+_COUNTERS = dict.fromkeys(("traces", "batches", "bytes_in", "bytes_out",
+                           "entries_real", "entries_padded"), 0)
 
 
 def trace_count() -> int:
-    return _TRACE_COUNT
+    return _COUNTERS["traces"]
+
+
+def counters() -> dict[str, int]:
+    """A copy of the engine's counters (see ``_COUNTERS``), totals since
+    import: read them before and after a run and take the difference."""
+    return dict(_COUNTERS)
 
 
 def _algo_init(algo, x0_p, coefs_p, mask_p):
@@ -209,8 +225,7 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
     miss the jit cache rather than silently run the shadowed round body.
     """
     del algo_gen  # participates only in the jit cache key
-    global _TRACE_COUNT
-    _TRACE_COUNT += 1  # trace-time side effect: counts compilations
+    _COUNTERS["traces"] += 1  # trace-time side effect: counts compilations
 
     from repro.core.algorithms import get_algorithm
 
@@ -233,6 +248,11 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
 
             Padded edge slots carry index (0, 0); whatever they scatter onto
             the diagonal is overwritten by the eye fill, so padding is exact.
+            Its ops carry ``sweep.expand`` in their HLO ``op_name``, which is
+            how a device trace tells the expansion from the rest of a round.
+            The sorts and fusions the TPU compiler makes of a large scatter
+            carry no metadata; ``bench/program_trace.py`` names them by
+            their users.
             """
             def one(bg, ig):
                 b = bg.astype(jnp.float32)
@@ -241,7 +261,8 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
                 m0 = m0.at[ig[:, 1], ig[:, 0]].set(b)
                 return m0
 
-            return jnp.where(eye, 1.0, jax.vmap(one)(bits_t, ei))
+            with jax.named_scope("sweep.expand"):
+                return jnp.where(eye, 1.0, jax.vmap(one)(bits_t, ei))
 
     # per-cell target: the true initial average over real nodes (padding is 0)
     xbar = x0.sum(axis=1, keepdims=True) * inv_n[:, None, None]   # (G, 1, F)
@@ -546,18 +567,9 @@ def run_batch(
       (x_final (G, N, F), mse (G, T+1, F)) as numpy arrays, plus the taps
       tuple when ``return_taps``.
     """
-    if backend not in ("jax", "pallas"):
-        raise ValueError(f"unknown backend {backend!r} (sweep runs 'jax' or 'pallas')")
-    from repro.core.algorithms import get_algorithm
-
-    sparse = ws is None
-    if sparse and (edges is None or edge_w is None or diag_w is None):
-        raise ValueError(
-            "sparse mode (ws=None) requires edges, edge_w and diag_w arrays")
-
-    x0 = np.asarray(x0)
-    f_total = x0.shape[2]
+    f_total = np.shape(x0)[2]
     if trial_chunk is not None and 0 < trial_chunk < f_total:
+        x0 = np.asarray(x0)
         outs = [
             run_batch(
                 ws, x0[:, :, s:s + trial_chunk], coefs, node_counts,
@@ -581,127 +593,197 @@ def run_batch(
         )
         return x_cat, m_cat, taps
 
-    if sparse:
-        edges = np.asarray(edges, dtype=np.int32)
-        edge_w = np.asarray(edge_w, dtype=np.float32)
-        diag_w = np.asarray(diag_w, dtype=np.float32)
-        if edge_w_rev is not None:
-            edge_w_rev = np.asarray(edge_w_rev, dtype=np.float32)
-    else:
-        ws = np.asarray(ws)
-    coefs = np.asarray(coefs)
-    g, n, f = x0.shape
-    if node_counts is None:
-        node_counts = np.full(g, n, dtype=np.int64)
-    node_counts = np.asarray(node_counts)
-    if algos is None:
-        algos = (("accel", 0, g),)
-    if [s for _, s, _ in algos] != [0] + [e for _, _, e in algos][:-1] \
-            or algos[-1][2] != g:
-        raise ValueError(f"algorithm layout {algos} does not tile G={g}")
-    # coalesce adjacent same-algorithm partitions (merged ensembles produce
-    # them) so the scan body keeps one fused round per distinct algorithm
-    merged = [list(algos[0])]
-    for name, s, e in algos[1:]:
-        if name == merged[-1][0]:
-            merged[-1][2] = e
-        else:
-            merged.append([name, s, e])
-    algos = tuple((n_, s_, e_) for n_, s_, e_ in merged)
-    parts_out = algos  # pre-G-padding layout; frames the returned taps
-    if round_masks is None and any(
-            get_algorithm(name).needs_schedule for name, _, _ in algos):
-        raise ValueError(
-            "this grid contains a schedule-bearing algorithm (async_pairwise): "
-            "pass round_masks=build_round_masks(ens, num_iters)")
+    # Host spans on the profiler's clock (no-ops without a profiler session):
+    # sweep.run_batch holds prep (validation, padding, packing), put (the
+    # host-to-device copy; folded into launch under a mesh), launch (the
+    # jitted call returning), wait (the device finishing) and fetch (the
+    # device-to-host copy and un-padding), in that order.
+    with TraceAnnotation("sweep.run_batch"):
+        with TraceAnnotation("sweep.prep"):
+            if backend not in ("jax", "pallas"):
+                raise ValueError(
+                    f"unknown backend {backend!r} (sweep runs 'jax' or 'pallas')")
+            from repro.core.algorithms import get_algorithm, registry_generation
 
-    bits = eidx = None
-    if round_masks is not None:
-        bits = np.asarray(round_masks.bits, dtype=np.uint8)
-        eidx = np.asarray(round_masks.idx, dtype=np.int32)
-        if bits.shape[0] != num_iters or bits.shape[1] != g:
-            raise ValueError(
-                f"round_masks bits {bits.shape} do not cover "
-                f"(num_iters={num_iters}, G={g}) rounds x cells"
-            )
-        if eidx.shape != (g, bits.shape[2], 2):
-            raise ValueError(
-                f"round_masks idx {eidx.shape} inconsistent with bits {bits.shape}"
-            )
+            sparse = ws is None
+            if sparse and (edges is None or edge_w is None or diag_w is None):
+                raise ValueError(
+                    "sparse mode (ws=None) requires edges, edge_w and diag_w arrays")
+            x0 = np.asarray(x0)
+            if sparse:
+                edges = np.asarray(edges, dtype=np.int32)
+                edge_w = np.asarray(edge_w, dtype=np.float32)
+                diag_w = np.asarray(diag_w, dtype=np.float32)
+                if edge_w_rev is not None:
+                    edge_w_rev = np.asarray(edge_w_rev, dtype=np.float32)
+            else:
+                ws = np.asarray(ws)
+            coefs = np.asarray(coefs)
+            g, n, f = x0.shape
+            if node_counts is None:
+                node_counts = np.full(g, n, dtype=np.int64)
+            node_counts = np.asarray(node_counts)
+            if algos is None:
+                algos = (("accel", 0, g),)
+            if [s for _, s, _ in algos] != [0] + [e for _, _, e in algos][:-1] \
+                    or algos[-1][2] != g:
+                raise ValueError(f"algorithm layout {algos} does not tile G={g}")
+            # coalesce adjacent same-algorithm partitions (merged ensembles
+            # produce them) so the scan body keeps one fused round per
+            # distinct algorithm
+            merged = [list(algos[0])]
+            for name, s, e in algos[1:]:
+                if name == merged[-1][0]:
+                    merged[-1][2] = e
+                else:
+                    merged.append([name, s, e])
+            algos = tuple((n_, s_, e_) for n_, s_, e_ in merged)
+            parts_out = algos  # pre-G-padding layout; frames the returned taps
+            if round_masks is None and any(
+                    get_algorithm(name).needs_schedule for name, _, _ in algos):
+                raise ValueError(
+                    "this grid contains a schedule-bearing algorithm "
+                    "(async_pairwise): pass "
+                    "round_masks=build_round_masks(ens, num_iters)")
 
-    n_orig, f_orig = n, f
-    tiles = None
-    wpack = None
-    if backend == "pallas" and sparse:
-        x0, wpack, tiles, n, f = _prep_pallas_sparse(
-            x0, edges, edge_w, diag_w, edge_counts, edge_w_rev)
-    elif backend == "pallas":
-        # pad N/F to the kernel's tile multiples ONCE, outside the scan; the
-        # node mask (below) keeps padded rows out of the MSE, padded trial
-        # columns are sliced off the outputs. The jax backend stays unpadded
-        # (padding a 20-node graph to 128 would be a ~40x flop tax there).
-        # The tiles chosen here are threaded into _sweep_scan as static args
-        # so padding and kernel blocking can never drift apart.
-        ws, x0, tiles, n, f = _prep_pallas_dense(ws, x0)
-    elif sparse:
-        wpack = _prep_jax_sparse(edges, edge_w, diag_w, edge_w_rev)
+            bits = eidx = None
+            if round_masks is not None:
+                bits = np.asarray(round_masks.bits, dtype=np.uint8)
+                eidx = np.asarray(round_masks.idx, dtype=np.int32)
+                if bits.shape[0] != num_iters or bits.shape[1] != g:
+                    raise ValueError(
+                        f"round_masks bits {bits.shape} do not cover "
+                        f"(num_iters={num_iters}, G={g}) rounds x cells"
+                    )
+                if eidx.shape != (g, bits.shape[2], 2):
+                    raise ValueError(
+                        f"round_masks idx {eidx.shape} inconsistent with "
+                        f"bits {bits.shape}"
+                    )
 
-    mask = (np.arange(n)[None, :] < node_counts[:, None]).astype(np.float32)
-    inv_n = (1.0 / node_counts).astype(np.float32)
+            n_orig, f_orig = n, f
+            tiles = None
+            wpack = None
+            if backend == "pallas" and sparse:
+                x0, wpack, tiles, n, f = _prep_pallas_sparse(
+                    x0, edges, edge_w, diag_w, edge_counts, edge_w_rev)
+            elif backend == "pallas":
+                # pad N/F to the kernel's tile multiples ONCE, outside the
+                # scan; the node mask (below) keeps padded rows out of the
+                # MSE, padded trial columns are sliced off the outputs. The
+                # jax backend stays unpadded (padding a 20-node graph to 128
+                # would be a ~40x flop tax there). The tiles chosen here are
+                # threaded into _sweep_scan as static args so padding and
+                # kernel blocking can never drift apart.
+                ws, x0, tiles, n, f = _prep_pallas_dense(ws, x0)
+            elif sparse:
+                wpack = _prep_jax_sparse(edges, edge_w, diag_w, edge_w_rev)
 
-    # G=1 (the simulate() degenerate sweep) gains nothing from the mesh and
-    # would pay device_count replicas of the whole problem via G-padding —
-    # only auto-engage the mesh for real grids.
-    if mesh is None and g > 1 and jax.device_count() > 1:
-        mesh = make_cpu_mesh()
+            mask = (np.arange(n)[None, :] < node_counts[:, None]).astype(np.float32)
+            inv_n = (1.0 / node_counts).astype(np.float32)
 
-    w_arrays = wpack if sparse else (ws,)
-    nw = len(w_arrays)
-    arrays = (*w_arrays, x0, mask, inv_n, coefs)
-    # which cell each row of the run's G axis holds, overall and per
-    # partition (a partition's carry comes back in its own row order)
-    rows = np.arange(g)
-    part_rows = [np.arange(s_, e_) for _, s_, e_ in algos]
-    if mesh is not None:
-        rows, part_rows, algos = _shard_rows(algos, mesh.shape["data"])
-        arrays = tuple(a[rows] for a in arrays)
-        if bits is not None:
-            bits, eidx = bits[:, rows], eidx[rows]
+            # G=1 (the simulate() degenerate sweep) gains nothing from the
+            # mesh and would pay device_count replicas of the whole problem
+            # via G-padding — only auto-engage the mesh for real grids.
+            if mesh is None and g > 1 and jax.device_count() > 1:
+                mesh = make_cpu_mesh()
 
-    from repro.core.algorithms import registry_generation
+            w_arrays = wpack if sparse else (ws,)
+            nw = len(w_arrays)
+            arrays = (*w_arrays, x0, mask, inv_n, coefs)
+            # which cell each row of the run's G axis holds, overall and per
+            # partition (a partition's carry comes back in its own row order)
+            rows = np.arange(g)
+            part_rows = [np.arange(s_, e_) for _, s_, e_ in algos]
+            if mesh is not None:
+                rows, part_rows, algos = _shard_rows(algos, mesh.shape["data"])
+                arrays = tuple(a[rows] for a in arrays)
+                if bits is not None:
+                    bits, eidx = bits[:, rows], eidx[rows]
 
-    statics = dict(
-        num_iters=num_iters, use_kernels=(backend == "pallas"), tiles=tiles,
-        layout=tuple(algos), algo_gen=registry_generation(), sparse=sparse)
-    if debug_checks:
-        from repro.analysis.coefficient import traced_coef_sites
+            statics = dict(
+                num_iters=num_iters, use_kernels=(backend == "pallas"),
+                tiles=tiles, layout=tuple(algos),
+                algo_gen=registry_generation(), sparse=sparse)
+            if debug_checks:
+                from repro.analysis.coefficient import traced_coef_sites
 
-        statics.update(debug_checks=True, dbg_sites=tuple(
-            tuple(sorted(traced_coef_sites(name))) for name, _, _ in algos))
-    ws_in = tuple(arrays[:nw]) if sparse else arrays[0]
-    if mesh is None and not debug_checks:
-        x_fin, mse, carry_fin = _sweep_scan(
-            ws_in, *arrays[nw:], bits=bits, eidx=eidx, **statics)
-    else:
-        x_fin, mse, carry_fin = _scan_program(
-            mesh, bits is not None, tuple(sorted(statics.items())))(
-                ws_in, *arrays[nw:], bits, eidx)
-    x_fin, mse = np.asarray(x_fin), np.asarray(mse)
-    # the first row of each cell carries it (pad rows repeat a cell)
-    first = np.unique(rows, return_index=True)[1]
-    x_fin = x_fin[first, :n_orig, :f_orig]
-    mse = mse[first, :, :f_orig]
-    if not return_taps:
-        return x_fin, mse
-    # Aux carry slots (everything past num_taps) are algorithm-internal
-    # estimator state, not network state: excluded by contract.
-    taps = []
-    for (name, s_p, e_p), prow, sub in zip(parts_out, part_rows, carry_fin):
-        keep = np.unique(prow, return_index=True)[1]
-        taps.append((name, s_p, e_p, tuple(
-            np.asarray(t)[keep, :n_orig, :f_orig]
-            for t in sub[:get_algorithm(name).num_taps])))
-    return x_fin, mse, tuple(taps)
+                statics.update(debug_checks=True, dbg_sites=tuple(
+                    tuple(sorted(traced_coef_sites(name))) for name, _, _ in algos))
+            real, padded = _entries(sparse, backend, node_counts, edge_counts,
+                                    edges, w_arrays, len(rows), n, f_orig, f)
+            _COUNTERS["batches"] += 1
+            _COUNTERS["entries_real"] += real
+            _COUNTERS["entries_padded"] += padded
+            _COUNTERS["bytes_in"] += _device_nbytes((arrays, bits, eidx))
+
+        if mesh is None:
+            with TraceAnnotation("sweep.put"):
+                arrays, bits, eidx = jax.block_until_ready(
+                    jax.device_put((arrays, bits, eidx)))
+        with TraceAnnotation("sweep.launch"):
+            ws_in = tuple(arrays[:nw]) if sparse else arrays[0]
+            if mesh is None and not debug_checks:
+                out = _sweep_scan(
+                    ws_in, *arrays[nw:], bits=bits, eidx=eidx, **statics)
+            else:
+                out = _scan_program(
+                    mesh, bits is not None, tuple(sorted(statics.items())))(
+                        ws_in, *arrays[nw:], bits, eidx)
+        with TraceAnnotation("sweep.wait"):
+            x_fin, mse, carry_fin = jax.block_until_ready(out)
+        with TraceAnnotation("sweep.fetch"):
+            x_fin, mse = np.asarray(x_fin), np.asarray(mse)
+            fetched = x_fin.nbytes + mse.nbytes
+            # the first row of each cell carries it (pad rows repeat a cell)
+            first = np.unique(rows, return_index=True)[1]
+            x_fin = x_fin[first, :n_orig, :f_orig]
+            mse = mse[first, :, :f_orig]
+            if not return_taps:
+                _COUNTERS["bytes_out"] += fetched
+                return x_fin, mse
+            # Aux carry slots (everything past num_taps) are algorithm-
+            # internal estimator state, not network state: excluded by
+            # contract.
+            taps = []
+            for (name, s_p, e_p), prow, sub in zip(parts_out, part_rows, carry_fin):
+                keep = np.unique(prow, return_index=True)[1]
+                full = [np.asarray(t) for t in sub[:get_algorithm(name).num_taps]]
+                fetched += sum(t.nbytes for t in full)
+                taps.append((name, s_p, e_p, tuple(
+                    t[keep, :n_orig, :f_orig] for t in full)))
+            _COUNTERS["bytes_out"] += fetched
+            return x_fin, mse, tuple(taps)
+
+
+def _device_nbytes(tree) -> int:
+    """Bytes the arrays of ``tree`` take on the device (JAX's dtypes:
+    float64 is stored as float32 unless x64 is on)."""
+    return sum(int(np.size(a)) * np.dtype(
+        jax.dtypes.canonicalize_dtype(np.asarray(a).dtype)).itemsize
+        for a in jax.tree.leaves(tree))
+
+
+def _entries(sparse, backend, node_counts, edge_counts, edges, w_arrays,
+             g_run, n, f_orig, f):
+    """(real, padded) round-matrix entries x trial columns of one batch.
+
+    Real: the entries of each cell's own graph (dense n_g^2, sparse 2 e_g
+    directed edges) over its F columns. Padded: what the round computes over
+    the ``g_run`` rows the device steps — dense G n^2 F at the padded
+    extents; ELL G n D F (``D`` the padded slot count); directed arrays
+    G 2 E_max F.
+    """
+    if not sparse:
+        return (int((node_counts.astype(np.int64) ** 2).sum()) * f_orig,
+                g_run * n * n * f)
+    ec = np.full(len(node_counts), edges.shape[1], np.int64) \
+        if edge_counts is None else np.asarray(edge_counts, np.int64)
+    real = 2 * int(ec.sum()) * f_orig
+    if backend == "pallas":
+        _, d, n_slots = w_arrays[0].shape            # slot-major (G, D, N)
+        return real, g_run * d * n_slots * f
+    return real, g_run * w_arrays[0].shape[1] * f   # (G, 2 E_max) directed
 
 
 def _shard_rows(algos, ndata: int):

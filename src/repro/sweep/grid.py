@@ -44,6 +44,7 @@ import math
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import accel, algorithms, dynamics, metrics, topology, weights
 from repro.core.accel import Theta
@@ -690,22 +691,29 @@ def build_round_masks(ens: Ensemble, num_iters: int, seed: int = 0) -> RoundMask
     nested across p. Schedule-bearing algorithms (``async_pairwise``) then
     post-process the dynamics draw through ``schedule_bits`` (the woken-edge
     one-hot ANDed with the failure bits) using the same stream.
+
+    Host spans on the profiler's clock: ``sweep.masks`` around the call,
+    ``sweep.masks.draw`` around each sampled cell's draw (the rest of
+    ``sweep.masks`` is the all-ones fill and the copies into ``bits``).
     """
-    specs = [dynamics.parse_dynamics(c.dynamics) for c in ens.configs]
-    algos = [algorithms.get_algorithm(c.algorithm) for c in ens.configs]
-    if all(s.is_static for s in specs) and not any(a.needs_schedule for a in algos):
-        return None
-    g = ens.num_configs
-    idx_list = [ens.edge_index(i) for i in range(g)]
-    e_max = max(1, max(len(ix) for ix in idx_list))
-    bits = np.ones((num_iters, g, e_max), dtype=np.uint8)
-    idx = np.zeros((g, e_max, 2), dtype=np.int32)
-    for i, (c, s, a, ix) in enumerate(zip(ens.configs, specs, algos, idx_list)):
-        e = len(ix)
-        idx[i, :e] = ix
-        if s.is_static and not a.needs_schedule:
-            continue                       # bits already all-ones
-        rng = dynamics.graph_rng(seed, (c.topology, c.n, c.graph_index))
-        cell_bits = dynamics.sample_edge_bits(s, num_iters, ix, c.n, rng)
-        bits[:, i, :e] = a.schedule_bits(cell_bits, ix, c.n, rng)
-    return RoundMasks(bits=bits, idx=idx)
+    with TraceAnnotation("sweep.masks"):
+        specs = [dynamics.parse_dynamics(c.dynamics) for c in ens.configs]
+        algos = [algorithms.get_algorithm(c.algorithm) for c in ens.configs]
+        if all(s.is_static for s in specs) and not any(a.needs_schedule for a in algos):
+            return None
+        g = ens.num_configs
+        idx_list = [ens.edge_index(i) for i in range(g)]
+        e_max = max(1, max(len(ix) for ix in idx_list))
+        bits = np.ones((num_iters, g, e_max), dtype=np.uint8)
+        idx = np.zeros((g, e_max, 2), dtype=np.int32)
+        for i, (c, s, a, ix) in enumerate(zip(ens.configs, specs, algos, idx_list)):
+            e = len(ix)
+            idx[i, :e] = ix
+            if s.is_static and not a.needs_schedule:
+                continue                       # bits already all-ones
+            with TraceAnnotation("sweep.masks.draw"):
+                rng = dynamics.graph_rng(seed, (c.topology, c.n, c.graph_index))
+                cell_bits = dynamics.sample_edge_bits(s, num_iters, ix, c.n, rng)
+                cell_bits = a.schedule_bits(cell_bits, ix, c.n, rng)
+            bits[:, i, :e] = cell_bits
+        return RoundMasks(bits=bits, idx=idx)
