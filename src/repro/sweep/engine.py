@@ -73,8 +73,13 @@ __all__ = ["SweepResult", "counters", "run_batch", "run_ensemble", "run_sweep",
 #   entries_real    round-matrix entries x trial columns of real nodes
 #   entries_padded  the same for what the round computes: dense G n_pad^2
 #                   F_pad, ELL G n_pad D F_pad, directed arrays G 2E F
+#   expand_real     real edges x rounds of the cells whose dense masks the
+#                   scan expands (dense dynamic batches only)
+#   expand_slots    the edge slots the expansion contracts over for them:
+#                   G E_pad T
 _COUNTERS = dict.fromkeys(("traces", "batches", "bytes_in", "bytes_out",
-                           "entries_real", "entries_padded"), 0)
+                           "entries_real", "entries_padded", "expand_real",
+                           "expand_slots"), 0)
 
 
 def trace_count() -> int:
@@ -85,6 +90,62 @@ def counters() -> dict[str, int]:
     """A copy of the engine's counters (see ``_COUNTERS``), totals since
     import: read them before and after a run and take the difference."""
     return dict(_COUNTERS)
+
+
+# Bytes the one-hot operands of one block of the dense mask expansion may
+# take (both operands, int8): the bound that holds where the compiler
+# materialises them instead of fusing them into the dot.
+_EXPAND_BLOCK_BYTES = 8 << 20
+
+
+def _expand_block(gp: int, e: int, n: int) -> int:
+    """Cells per block of the dense mask expansion: the largest divisor of
+    ``gp`` whose two (cells, E, N) int8 one-hots fit _EXPAND_BLOCK_BYTES,
+    so equal blocks tile the partition."""
+    most = max(1, _EXPAND_BLOCK_BYTES // (2 * e * n))
+    return max(k for k in range(1, min(gp, most) + 1) if gp % k == 0)
+
+
+def _expand_mask(bits_t, ei, n: int):
+    """(Gp, E) bits of one round -> (Gp, N, N) dense 0/1 mask: 1 on live
+    edges and on the diagonal.
+
+    One contraction per cell on the MXU: a link that is down this round
+    points at no node, and with the 0/1 one-hots S[e, i] = [src_e = i] and
+    D[e, j] = [dst_e = j] of the live links, A = S^T D holds each live edge
+    once; the mask is A + A^T with the diagonal set to 1. Both one-hots
+    depend on the round's bits, so neither is loop-invariant and hoisted out
+    of the scan whole. Edges are canonical (i < j) and distinct, so every
+    off-diagonal entry sums at most one nonzero term: int8 operands with
+    int32 accumulation give it exactly. Padded edge slots carry index (0, 0)
+    and land on the diagonal, which the eye fill overwrites, so padding is
+    exact. The cells go through in equal blocks (``_expand_block``),
+    unrolled and one after another: the one-hots' bytes stay bounded whether
+    or not the compiler fuses them into the dot, and the scan over rounds
+    stays the program's one loop. Its ops, the dot's fusion included, carry
+    ``sweep.expand`` in their HLO ``op_name``, which is how a device trace
+    tells the expansion from the rest of a round.
+    """
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n), 2)
+    eye = jnp.eye(n, dtype=bool)
+    gp, e = bits_t.shape
+    k = _expand_block(gp, e, n)
+    with jax.named_scope("sweep.expand"):
+        # a link that is down points at no node
+        live = jnp.where(bits_t[:, :, None] != 0, ei, -1)          # (Gp, E, 2)
+        m, a = jnp.zeros((gp, n, n), jnp.float32), None
+        for c in range(0, gp, k):
+            ic = live[c:c + k]
+            if a is not None:
+                # a count, never negative: adds 0, and orders the blocks so
+                # that one block's one-hots are live at a time
+                ic = ic + jnp.minimum(a[0, 0, 0], 0)
+            s = (lanes == ic[:, :, :1]).astype(jnp.int8)
+            d = (lanes == ic[:, :, 1:]).astype(jnp.int8)
+            a = jnp.einsum("gen,gem->gnm", s, d, preferred_element_type=jnp.int32)
+            blk = jnp.where(eye, 1.0, (a + jnp.swapaxes(a, 1, 2)).astype(jnp.float32))
+            m = jax.lax.dynamic_update_slice(m, blk, (c, 0, 0))
+        return m
 
 
 def _algo_init(algo, x0_p, coefs_p, mask_p):
@@ -206,8 +267,9 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
 
     ``bits``/``eidx`` (None on the static path) carry the compressed
     (T, G, E) uint8 edge-activity schedule: the scan expands each round's
-    bits into the dense (G, N, N) 0/1 mask *inside* the body — one round's
-    mask lives in registers/VMEM while the per-round effective matrices
+    bits into the dense (G, N, N) 0/1 mask *inside* the body
+    (``_expand_mask``, a one-hot contraction on the MXU) — one round's mask
+    exists at a time, while the per-round effective matrices
     W_eff(t) = W.*M + diag((W.*(1-M))@1) are never materialized in HBM
     (``repro.core.dynamics`` has the model; ``async_pairwise`` rides the
     same machinery with one-hot bits over its pairwise base matrix).
@@ -238,31 +300,6 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
     dynamic = bits is not None
     if layout is None:
         layout = (("accel", 0, x0.shape[0]),)
-
-    if dynamic and not sparse:
-        n = ws.shape[1]
-        eye = jnp.eye(n, dtype=bool)
-
-        def expand(bits_t, ei):
-            """(Gp, E) bits -> (Gp, N, N) dense mask: 1 on live edges + diag.
-
-            Padded edge slots carry index (0, 0); whatever they scatter onto
-            the diagonal is overwritten by the eye fill, so padding is exact.
-            Its ops carry ``sweep.expand`` in their HLO ``op_name``, which is
-            how a device trace tells the expansion from the rest of a round.
-            The sorts and fusions the TPU compiler makes of a large scatter
-            carry no metadata; ``bench/program_trace.py`` names them by
-            their users.
-            """
-            def one(bg, ig):
-                b = bg.astype(jnp.float32)
-                m0 = jnp.zeros((n, n), jnp.float32)
-                m0 = m0.at[ig[:, 0], ig[:, 1]].set(b)
-                m0 = m0.at[ig[:, 1], ig[:, 0]].set(b)
-                return m0
-
-            with jax.named_scope("sweep.expand"):
-                return jnp.where(eye, 1.0, jax.vmap(one)(bits_t, ei))
 
     # per-cell target: the true initial average over real nodes (padding is 0)
     xbar = x0.sum(axis=1, keepdims=True) * inv_n[:, None, None]   # (G, 1, F)
@@ -343,7 +380,7 @@ def _sweep_scan(ws, x0, mask, inv_n, coefs, num_iters: int, use_kernels: bool,
         for i, ((algo, s, e, prim), sub) in enumerate(zip(parts, carry)):
             if dynamic:
                 m = bits_t[s:e].astype(jnp.float32) if sparse \
-                    else expand(bits_t[s:e], eidx[s:e])
+                    else _expand_mask(bits_t[s:e], eidx[s:e], ws.shape[1])
             else:
                 m = None
             if debug_checks:
@@ -647,6 +684,7 @@ def run_batch(
                     "round_masks=build_round_masks(ens, num_iters)")
 
             bits = eidx = None
+            real_edges = 0
             if round_masks is not None:
                 bits = np.asarray(round_masks.bits, dtype=np.uint8)
                 eidx = np.asarray(round_masks.idx, dtype=np.int32)
@@ -660,6 +698,8 @@ def run_batch(
                         f"round_masks idx {eidx.shape} inconsistent with "
                         f"bits {bits.shape}"
                     )
+                # padded edge slots are (0, 0); a real edge has i < j
+                real_edges = int(np.count_nonzero(eidx[..., 0] != eidx[..., 1]))
 
             n_orig, f_orig = n, f
             tiles = None
@@ -715,6 +755,10 @@ def run_batch(
             _COUNTERS["batches"] += 1
             _COUNTERS["entries_real"] += real
             _COUNTERS["entries_padded"] += padded
+            if bits is not None and not sparse:
+                _COUNTERS["expand_real"] += real_edges * num_iters
+                _COUNTERS["expand_slots"] += \
+                    len(rows) * eidx.shape[1] * num_iters
             _COUNTERS["bytes_in"] += _device_nbytes((arrays, bits, eidx))
 
         if mesh is None:

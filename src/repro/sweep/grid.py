@@ -666,8 +666,9 @@ class RoundMasks:
 
     ``bits[t, g, e]`` = 1 iff edge ``idx[g, e]`` of cell g is up in round t.
     Cells are padded to the grid's largest edge count with index (0, 0) and
-    bit 1 — the engine's dense expansion overwrites the diagonal with ones,
-    so padded slots are inert. uint8 keeps a (T, G, E) schedule ~32x smaller
+    bit 1 — whatever such a slot adds lands on the diagonal of the engine's
+    dense mask, which its expansion then sets to ones, so padded slots are
+    inert. uint8 keeps a (T, G, E) schedule ~32x smaller
     than the per-round W matrices it replaces.
     """
 

@@ -111,6 +111,37 @@ def test_counters_follow_the_dense_shapes(backend):
 
 
 @pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_expand_counters_follow_the_dense_lossy_shapes(backend):
+    ens = build_ensemble(LOSSY)
+    t = 3
+    masks = build_round_masks(ens, t, seed=1)
+    _, d = _delta(lambda: run_ensemble(ens, num_iters=t, backend=backend,
+                                       round_masks=masks))
+    g = ens.num_configs
+    real = sum(len(ens.edge_index(i)) for i in range(g))
+    e_pad = masks.bits.shape[2]
+    assert real < g * e_pad            # chains of 8 and 12 nodes: 7 and 11 edges
+    assert d["expand_real"] == real * t
+    assert d["expand_slots"] == g * e_pad * t
+
+
+@pytest.mark.parametrize("layout", ["static", "sparse"])
+def test_expand_counters_skip_what_expands_nothing(layout):
+    if layout == "static":
+        ens = build_ensemble(LOSSY)
+        masks = None
+    else:
+        ens = build_ensemble(SweepSpec(topologies=("grid2d",), sizes=(16, 25),
+                                       designs=("memoryless",), num_trials=2,
+                                       dynamics=("bernoulli:0.2",), layout="sparse"))
+        masks = build_round_masks(ens, 3, seed=1)
+    _, d = _delta(lambda: run_ensemble(ens, num_iters=3, backend="jax",
+                                       round_masks=masks))
+    assert d["batches"] == 1
+    assert d["expand_real"] == d["expand_slots"] == 0
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
 def test_counters_follow_the_sparse_shapes(backend):
     ens = build_ensemble(SweepSpec(topologies=("grid2d",), sizes=(16, 25),
                                    designs=("memoryless", "asymptotic"),
