@@ -5,10 +5,10 @@ and algorithms; ``cells`` turns that into one ``Cell`` per grid cell in the
 order the sweep grid stacks them (algorithm outermost, then graph, then
 design, then dynamics). Conventions follow the paper (arXiv:0903.3537):
 
-* chain and 2-D grid (rows x cols nearest to square, row-major node ids);
-  random geometric graphs on the unit square with radius sqrt(2 log n / n),
-  redrawn until connected, from one generator seeded with the configuration's
-  graph seed, draw after draw in grid order;
+* graph families are modules of ``families/``, found by the head of the
+  topology spec (``"ba:10"`` -> ``families/ba.py``), each with its own
+  convention; a random family draws from one generator seeded with the
+  configuration's graph seed, draw after draw in grid order;
 * Metropolis-Hastings weights W_ij = 1 / (1 + max(d_i, d_j)), replaced by the
   lazy (I + W) / 2 where |lambda_N| > lambda_2 (Theorem 1's condition);
 * lambda_2 exact (eigvalsh) up to ``EXACT_SPECTRUM_MAX`` nodes; above it the
@@ -24,15 +24,21 @@ design, then dynamics). Conventions follow the paper (arXiv:0903.3537):
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
+import re
+from pathlib import Path
 
 import numpy as np
 import scipy.sparse as sp
 
+from . import families
+
 EXACT_SPECTRUM_MAX = 1024
 POWER_ITERS = 500
 POWER_TOL = 1e-12
-RANDOM_FAMILIES = ("rgg",)
+FAMILIES = Path(families.__file__).resolve().parent
+FAMILY_NAME = re.compile(r"^[A-Za-z][A-Za-z0-9_]*$")
 
 THETAS = {
     "memoryless": None,
@@ -78,68 +84,24 @@ class Cell:
     weights: Weights | None    # the symmetric base (accel), None for push-sum
 
 
-def near_square(n: int) -> tuple[int, int]:
-    rows = max(math.isqrt(n), 1)
-    while n % rows:
-        rows -= 1
-    return rows, n // rows
-
-
-def _canonical(i: np.ndarray, j: np.ndarray) -> np.ndarray:
-    lo, hi = np.minimum(i, j), np.maximum(i, j)
-    order = np.lexsort((hi, lo))
-    return np.stack([lo[order], hi[order]], axis=1).astype(np.int64)
-
-
-def chain_edges(n: int) -> np.ndarray:
-    i = np.arange(n - 1)
-    return _canonical(i, i + 1)
-
-
-def grid_edges(n: int) -> np.ndarray:
-    rows, cols = near_square(n)
-    i = np.arange(n)
-    r, c = np.divmod(i, cols)
-    right, down = i[c < cols - 1], i[r < rows - 1]
-    return _canonical(np.concatenate([right, down]),
-                      np.concatenate([right + 1, down + cols]))
-
-
-def _connected(n: int, edges: np.ndarray) -> bool:
-    adj = sp.coo_matrix((np.ones(len(edges)), (edges[:, 0], edges[:, 1])),
-                        shape=(n, n))
-    ncomp, _ = sp.csgraph.connected_components(adj, directed=False)
-    return ncomp == 1
-
-
-def rgg_edges(n: int, rng: np.random.Generator, max_tries: int = 200) -> np.ndarray:
-    r = float(np.sqrt(2.0 * np.log(n) / n))
-    for _ in range(max_tries):
-        pts = rng.random((n, 2))
-        d2 = ((pts[:, None, :] - pts[None, :, :]) ** 2).sum(-1)
-        i, j = np.nonzero(np.triu(d2 <= r * r, k=1))
-        edges = np.stack([i, j], axis=1).astype(np.int64)
-        if _connected(n, edges):
-            return edges
-    raise RuntimeError(f"no connected RGG(n={n}) in {max_tries} draws")
+def family(spec: str):
+    """(module, args) of a topology spec ``<family>[:arg...]``: the module of
+    ``families/`` named by its head, and the fields after the first ``:``."""
+    head, *args = str(spec).split(":")
+    if not FAMILY_NAME.match(head) or not (FAMILIES / f"{head}.py").is_file():
+        raise ValueError(f"no reference for topology {spec!r}")
+    return importlib.import_module(f"{families.__name__}.{head}"), args
 
 
 def draw_graphs(topologies, sizes, graph_trials: int, graph_seed: int) -> list[Graph]:
     """Every graph of the grid, in grid order, from one seeded generator."""
     rng = np.random.default_rng(graph_seed)
     out = []
-    for fam in topologies:
+    for spec in topologies:
+        fam, args = family(spec)
         for n in sizes:
-            for d in range(graph_trials if fam in RANDOM_FAMILIES else 1):
-                if fam == "chain":
-                    e = chain_edges(n)
-                elif fam == "grid2d":
-                    e = grid_edges(n)
-                elif fam == "rgg":
-                    e = rgg_edges(n, rng)
-                else:
-                    raise ValueError(f"no reference for topology {fam!r}")
-                out.append(Graph(fam, int(n), d, e))
+            for d in range(graph_trials if fam.RANDOM else 1):
+                out.append(Graph(spec, int(n), d, fam.edges(int(n), args, rng)))
     return out
 
 
@@ -237,8 +199,9 @@ def layout(config: dict, cell: dict) -> list[tuple]:
     out = []
     for algo in cell["algorithms"]:
         for fam in config["topologies"]:
+            draws = config["graph_trials"] if family(fam)[0].RANDOM else 1
             for n in config["sizes"]:
-                for d in range(config["graph_trials"] if fam in RANDOM_FAMILIES else 1):
+                for d in range(draws):
                     designs = config["designs"] if algo == "accel" else (algo,)
                     out += [(fam, int(n), d, algo, des, dyn)
                             for des in designs for dyn in cell["dynamics"]]

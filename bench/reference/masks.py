@@ -13,6 +13,8 @@ import zlib
 
 import numpy as np
 
+BLOCK_DRAWS = 1 << 22      # uniforms held at once while a schedule is drawn
+
 
 def graph_rng(seed: int, key: tuple) -> np.random.Generator:
     return np.random.default_rng([int(seed), zlib.crc32(repr(key).encode("utf-8"))])
@@ -24,6 +26,13 @@ def edge_bits(dynamics: str, seed: int, key: tuple, rounds: int, num_edges: int)
     if kind == "static":
         return np.ones((rounds, num_edges), dtype=bool)
     if kind == "bernoulli":
-        u = graph_rng(seed, key).random((rounds, num_edges))
-        return u >= float(params[0])
+        # rows drawn a block at a time, the same stream as one (T, E) draw:
+        # a schedule of 1e6 edges over thousands of rounds holds its bits,
+        # not 8 bytes a draw
+        rng, p = graph_rng(seed, key), float(params[0])
+        bits = np.empty((rounds, num_edges), dtype=bool)
+        step = max(1, BLOCK_DRAWS // max(num_edges, 1))
+        for t in range(0, rounds, step):
+            bits[t:t + step] = rng.random((min(step, rounds - t), num_edges)) >= p
+        return bits
     raise ValueError(f"no reference schedule for dynamics {dynamics!r}")

@@ -1,9 +1,13 @@
 """The harness is driven by files: BENCHMARK.json agrees with them, and a
-cell or a metric is added by adding a file, editing none."""
+cell, a metric, a configuration or a graph family is added by adding a
+file, editing none."""
 import hashlib
 import json
+import os
 import re
 import shutil
+import subprocess
+import sys
 from pathlib import Path
 
 from bench import registry
@@ -60,3 +64,68 @@ def test_a_cell_and_a_metric_are_added_as_files(tmp_path):
     assert added["config_data"]["name"] == "sensor_field"
     assert list(registry.metrics_for("sensor_field.heavy_loss", root)) == ["sweeps_done"]
     assert "sweeps_done" not in registry.metrics_for("sensor_field.lossy", root)
+
+
+RING = '''"""Ring: node i joined to node i + 1, and node n - 1 to node 0."""
+import numpy as np
+
+from . import canonical
+
+RANDOM = False
+
+
+def edges(n, args, rng):
+    i = np.arange(n)
+    return canonical(i, (i + 1) % n)
+'''
+
+TAKE = '''import json, sys
+from bench import registry, reference, work
+assert reference.graphs.__file__.startswith(sys.argv[1]), reference.graphs.__file__
+cell = registry.workload("overlay_ba.lossy")
+cfg = cell["config_data"]
+cells = reference.graphs.cells(cfg, cell)
+shapes = work.shapes_from_reference(cfg, cell)
+print(json.dumps({"cells": [[c.graph.family, c.graph.n, c.graph.draw, c.algorithm, c.design,
+                             c.dynamics, len(c.graph.edges), c.weights is not None]
+                            for c in cells],
+                  "shapes": [[s.n, s.edges, s.algorithm, s.lossy] for s in shapes]}))
+'''
+
+
+def test_a_configuration_and_a_graph_family_are_added_as_files(tmp_path):
+    """A Barabási–Albert deployment, its cell and a family the reference did
+    not have, added to a copy of the harness, are taken by the reference and
+    the work count of that copy."""
+    root = tmp_path / "bench"
+    shutil.copytree(ROOT / "bench", root,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = digest(root)
+    config = json.loads((root / "configs" / "sensor_field.json").read_text())
+    config.update(name="overlay_ba", topologies=["ba:3", "ring"], sizes=[40, 64],
+                  designs=["asymptotic"], graph_trials=2, num_trials=4, layout="sparse")
+    (root / "configs" / "overlay_ba.json").write_text(json.dumps(config))
+    cell = json.loads((root / "cells" / "sensor_field.lossy.json").read_text())
+    cell.update(config="overlay_ba", traffic="lossy", num_iters=50)
+    (root / "cells" / "overlay_ba.lossy.json").write_text(json.dumps(cell))
+    (root / "reference" / "families" / "ring.py").write_text(RING)
+    after = digest(root)
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert sorted(set(after) - set(before)) == [
+        "cells/overlay_ba.lossy.json", "configs/overlay_ba.json", "reference/families/ring.py"]
+
+    out = subprocess.run([sys.executable, "-c", TAKE, str(tmp_path)], cwd=tmp_path,
+                         env={**os.environ, "PYTHONPATH": str(tmp_path)},
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    got = json.loads(out.stdout.splitlines()[-1])
+    graphs = [("ba:3", n, d) for n in (40, 64) for d in (0, 1)] + \
+        [("ring", n, 0) for n in (40, 64)]
+    # accel: one cell per graph, design and dynamics; push-sum: per graph, dynamics
+    want = [[fam, n, d, algo, "asymptotic" if algo == "accel" else algo, dyn,
+             3 * (n - 3) if fam == "ba:3" else n, algo == "accel"]
+            for algo in ("accel", "push_sum") for fam, n, d in graphs
+            for dyn in cell["dynamics"]]
+    assert got["cells"] == want
+    assert got["shapes"] == [[n, e, algo, dyn != "static"]
+                             for _f, n, _d, algo, _des, dyn, e, _w in want]

@@ -76,9 +76,42 @@ def test_count_by_hand_and_state_traffic():
     nnz = 10 + 18
     assert flops == 3 * (2 * nnz * 2 + 3 * 10 * 2)
     assert bytes_ == 4 * nnz + 4 * (2 * 10 * 2 + 4 * 2)
-    # a cell whose state does not fit on chip moves it every round
+    # a cell whose state does not fit on chip moves it every round, and its
+    # nonzeros (value and column index) are read every round in place of once
     _, streamed = work.sweep_work([shape], 2, 3, 10.0)
-    assert streamed == bytes_ + 3 * (2 * 10 * 2 * 4)
+    assert streamed == bytes_ + 3 * (2 * 10 * 2 * 4) + 3 * 8 * nnz - 4 * nnz
+
+
+def test_count_by_hand_with_the_graph_off_chip():
+    """A lossy push-sum cell, 10 nodes, 12 edges, F = 2, T = 5, whose 34
+    nonzeros (8 bytes each: 272) pass an on-chip memory of 200 bytes while
+    its state (2 x 10 x 2 float32: 160) fits."""
+    shape = work.CellShape(10, 12, "push_sum", (False, False, False), True)
+    flops, bytes_ = work.sweep_work([shape], 2, 5, 200.0)
+    nnz = 10 + 24
+    per_round = 2 * 2 * nnz * 2 + 2 * 12 * 2 + 10 * 2 + 3 * 10 * 2
+    assert flops == 5 * per_round
+    bits = 12 * 5 / 8
+    x0_x_mse = 4 * (2 * 10 * 2 + 6 * 2)
+    assert bytes_ == 5 * 8 * nnz + bits + x0_x_mse
+    # on a chip that holds the nonzeros they are read once, as float32 values
+    assert work.sweep_work([shape], 2, 5, 272.0) == (flops, 4 * nnz + bits + x0_x_mse)
+
+
+# (flops, bytes) of one sweep of each cell at its own sizes on a TPU v5 lite,
+# as counted before graphs too large for on-chip memory were counted apart
+SENSOR_FIELD_WORK = {
+    "sensor_field.lossy": (345553700000.0, 290401096.0),
+    "sensor_field.static": (107636736000.0, 103624536.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SENSOR_FIELD_WORK))
+def test_sensor_field_counts_are_unchanged(name):
+    cell = registry.workload(name)
+    onchip = work.peaks("TPU v5 lite")["onchip_bytes"]
+    shapes = work.shapes_from_reference(cell["config_data"], cell)
+    assert count(shapes, cell, onchip) == SENSOR_FIELD_WORK[name]
 
 
 def test_peaks_table_refuses_an_unknown_chip():

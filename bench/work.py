@@ -15,7 +15,9 @@ Bytes are each input and output of the sweep moved once: W's nonzeros
 (float32 values), one bit per edge and round of a lossy schedule, x0 and
 x_final (float32), the MSE trajectory (float32). A cell whose carried state
 (taps x n x F float32) does not fit the chip's on-chip memory
-(``peaks.json``) reads and writes it every round besides.
+(``peaks.json``) reads and writes it every round besides. A cell whose
+nonzeros (a float32 value and an int32 column index each) do not fit it
+reads them every round in place of once.
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ from pathlib import Path
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 F32 = 4
+I32 = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,7 +68,9 @@ def sweep_work(cells: list[CellShape], f: int, rounds: int, onchip_bytes: float)
         per_round += c.n * f * (c.algorithm == "push_sum")
         per_round += 3 * c.n * f
         flops += per_round * rounds
-        bytes_ += F32 * nnz + (c.edges * rounds / 8 if c.lossy else 0)
+        graph = (F32 + I32) * nnz
+        bytes_ += rounds * graph if graph > onchip_bytes else F32 * nnz
+        bytes_ += c.edges * rounds / 8 if c.lossy else 0
         bytes_ += F32 * (2 * c.n * f + (rounds + 1) * f)
         carried = (1 + cc) * states if c.algorithm == "accel" else states
         state = carried * c.n * f * F32
